@@ -4,8 +4,7 @@ from .controller import ControllerConfig, ControllerStats, \
     RecompileScheduler, SamplingConfig
 from .ctx import DataPlaneCtx
 from .engine import EngineConfig, MorpheusEngine
-from .execcache import CacheStats, ExecutableCache, \
-    enable_persistent_xla_cache
+from .execcache import CacheStats, ExecutableCache
 from .histogram import StreamingHistogram
 from .instrument import AdaptiveController, SketchConfig, \
     SketchDoubleBuffer

@@ -86,7 +86,8 @@ class DataPlaneCtx:
                 and site_id in self.instr):
             self._record(site_id, idx)
         return dispatch_lookup(self.plan, site_id, name, self.tables,
-                               idx, fields, self.guards)
+                               idx, fields, self.guards, self.mesh,
+                               self.instr_axes)
 
     def lookup_or_none(self, name: str, idx: jax.Array,
                        fields: Optional[Tuple[str, ...]] = None):
@@ -102,7 +103,8 @@ class DataPlaneCtx:
                 and site_id in self.instr):
             self._record(site_id, idx)
         return dispatch_lookup(self.plan, site_id, name, self.tables,
-                               idx, fields, self.guards)
+                               idx, fields, self.guards, self.mesh,
+                               self.instr_axes)
 
     def update(self, name: str, idx: jax.Array,
                values: Dict[str, jax.Array]) -> None:

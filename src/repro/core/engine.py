@@ -72,8 +72,6 @@ class EngineConfig:
                                     # same ns + same cache => runtimes
                                     # share executables (requires equal
                                     # step fn / schemas / shapes)
-    xla_cache_dir: Optional[str] = None  # persistent XLA compile cache:
-                                         # warm restarts skip t2
 
     @property
     def n_instr_shards(self) -> Optional[int]:
@@ -108,15 +106,6 @@ class MorpheusEngine:
         self.lower_count = 0
         self.compile_count = 0
         self._count_lock = threading.Lock()
-        if self.cfg.xla_cache_dir is not None:
-            from .execcache import enable_persistent_xla_cache
-            if not enable_persistent_xla_cache(self.cfg.xla_cache_dir):
-                import warnings
-                warnings.warn(
-                    f"xla_cache_dir={self.cfg.xla_cache_dir!r} requested "
-                    f"but this jax build lacks the persistent "
-                    f"compilation-cache knobs — warm restarts will pay "
-                    f"full t2", stacklevel=2)
 
     # ---- §4.1 static code analysis ---------------------------------------
     def analyze(self, params, example_batch) -> Dict[str, Any]:

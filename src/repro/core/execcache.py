@@ -33,11 +33,9 @@ the same compilation.  Raw concurrent ``get``/``put`` on the same key
 remains last-write-wins (waste, not corruption) for callers that bypass
 ``get_or_compile``.
 
-:func:`enable_persistent_xla_cache` is the second layer: pointing JAX's
-persistent compilation cache at a directory makes warm *restarts* skip
-``t2`` for every executable this process (or a previous one) already
-built — wired through ``EngineConfig.xla_cache_dir`` and
-``launch/serve.py --xla-cache-dir``.
+The second layer, across processes, is JAX's persistent compilation
+cache, which the entry points turn on
+(:func:`repro.launch.compile_cache.enable_compile_cache`).
 """
 from __future__ import annotations
 
@@ -248,54 +246,3 @@ class ExecutableCache:
         with self._lock:
             self._entries.clear()
 
-
-_ACTIVE_XLA_CACHE_DIR: Optional[str] = None
-
-
-def enable_persistent_xla_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` so warm
-    restarts skip ``t2`` for already-built executables.  Thresholds are
-    dropped to zero — data-plane executables are small but recompiled
-    continuously, exactly the workload the defaults exclude.  The cache
-    object is latched on the first compile of the process, so it is
-    explicitly reset after the config change; the engine can therefore
-    enable it mid-process (jax ops already run).
-
-    The setting is PROCESS-GLOBAL (it is jax config, not per-engine):
-    re-enabling the same directory is a no-op, and pointing a second
-    engine at a *different* directory redirects every engine in the
-    process (a warning says so).  Returns False (and changes nothing) on
-    jax builds without the knobs."""
-    global _ACTIVE_XLA_CACHE_DIR
-    path = str(path)
-    if _ACTIVE_XLA_CACHE_DIR == path:
-        return True                      # already active: don't re-latch
-    knobs = (("jax_compilation_cache_dir", path),
-             ("jax_persistent_cache_min_entry_size_bytes", -1),
-             ("jax_persistent_cache_min_compile_time_secs", 0))
-    prev = {}
-    try:
-        for name, _ in knobs:            # probe BEFORE mutating any
-            prev[name] = getattr(jax.config, name)
-        for name, value in knobs:
-            jax.config.update(name, value)
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except (AttributeError, ImportError, ValueError):
-        # honor the "changes nothing on failure" contract: restore every
-        # knob that was touched — caching must not be left half-enabled
-        for name, value in prev.items():
-            try:
-                jax.config.update(name, value)
-            except (AttributeError, ValueError):
-                pass
-        return False
-    if _ACTIVE_XLA_CACHE_DIR is not None:
-        import warnings
-        warnings.warn(
-            f"persistent XLA cache redirected from "
-            f"{_ACTIVE_XLA_CACHE_DIR!r} to {path!r} — the setting is "
-            f"process-global and now applies to every engine",
-            stacklevel=2)
-    _ACTIVE_XLA_CACHE_DIR = path
-    return True

@@ -80,10 +80,13 @@ def moe_ffn_hotpath(params, x2d: jax.Array, cfg: ModelConfig,
 
     gates, ids, logits = route(params["w_router"], x2d, K,
                                params.get("b_router"))
-    # remap: global expert id -> hot slot (or -1)
-    remap = jnp.full((E,), -1, jnp.int32).at[hot_arr].set(
-        jnp.arange(H, dtype=jnp.int32))
-    hot_ids = remap[ids]                              # (T,K)
+    # remap: global expert id -> hot slot (or -1), a trace-time constant.
+    # Built on the host: as an in-graph scatter inside a lax.scan body
+    # (fused windows) the TPU compiler aborts on it when the hot set is
+    # 0..H-1 (indices and updates fold to the same iota).
+    remap_np = np.full((E,), -1, np.int32)
+    remap_np[list(hot_experts)] = np.arange(H, dtype=np.int32)
+    hot_ids = jnp.asarray(remap_np)[ids]              # (T,K)
     all_hot = jnp.all(hot_ids >= 0)
 
     def fast():

@@ -203,6 +203,8 @@ StreamingHistogram` series in ``hists`` — so p50/p99 everywhere in the
     straggler_events: int = 0     # StragglerMonitor mitigations fired
     requests_rejected_degraded: int = 0   # admissions shed PLANE_DEGRADED
     requests_failed: int = 0      # in-flight requests lost to a fault
+    warm_errors: List[str] = field(default_factory=list)  # failed
+                                  # background fused-generic warms
     t1_history: List[float] = field(default_factory=list)
     t2_history: List[float] = field(default_factory=list)
     swap_history: List[float] = field(default_factory=list)
@@ -1037,8 +1039,10 @@ class MorpheusRuntime:
         """Background warm of the fused generic executable for a newly
         seen (batch structure, K): compiled through the shared cache's
         in-flight dedup, kept out of the serving counters (it is
-        insurance, not a Morpheus cycle).  Best-effort — a failure here
-        just means the first deopt window pays the compile inline."""
+        insurance, not a Morpheus cycle).  A failure is recorded in
+        ``stats.warm_errors`` (`launch/serve.py` fails the run on it):
+        serving goes on, and the first deopt window pays the compile
+        inline."""
         try:
             isites = self._active_isites
             key = self._exec_key(self.generic_plan, avals,
@@ -1049,8 +1053,8 @@ class MorpheusRuntime:
                     state=self.state.replace(
                         instr=self.engine.init_instr_state(isites)),
                     instr_struct=isites, serving=False, fuse=k)
-        except Exception:
-            pass
+        except Exception as e:  # noqa: BLE001 — background thread boundary
+            self.stats.log("warm_errors", f"{type(e).__name__}: {e}")
 
     def _fused_exec(self, plan: SpecializationPlan, stacked,
                     instr_struct: Tuple[str, ...], k: int

@@ -117,7 +117,29 @@ def _onehot(table_state, idx, fields, n_valid: int):
     return out
 
 
-def _hot_cache(table_state, idx, fields, hot_keys_arr):
+def _sharded_hot_gather(table, hot_rows, hot_ids, flat_idx, mesh, axes):
+    """``kops.hot_gather`` on a device mesh.  A Pallas kernel cannot be
+    partitioned by XLA, so it runs under ``shard_map``: every device
+    gathers its own shard of the queries from the replicated table and
+    hot rows.  Queries are padded with row 0 to a multiple of the shard
+    count; the padded rows are dropped."""
+    from jax.sharding import PartitionSpec as P
+    from ..distributed.compat import shard_map
+
+    n = int(np.prod([mesh.shape[a] for a in axes]))
+    T = flat_idx.shape[0]
+    pad = (-T) % n
+    if pad:
+        flat_idx = jnp.pad(flat_idx, (0, pad))
+    spec = P(tuple(axes))
+    res = shard_map(kops.hot_gather, mesh=mesh,
+                    in_specs=(P(), P(), P(), spec),
+                    out_specs=spec)(table, hot_rows, hot_ids, flat_idx)
+    return res[:T]
+
+
+def _hot_cache(table_state, idx, fields, hot_keys_arr, mesh=None,
+               axes=("data",)):
     """Fast-path cache (§4.3.1): heavy-hitter rows served from a small
     VMEM-resident copy (Pallas ``hot_gather`` on TPU), cold rows from the
     full HBM table.  Semantics identical to a plain gather."""
@@ -129,7 +151,11 @@ def _hot_cache(table_state, idx, fields, hot_keys_arr):
         if t.ndim >= 2 and jnp.issubdtype(t.dtype, jnp.floating):
             hot_rows = jnp.take(t, hot_ids, axis=0)
             flat_idx = idx.reshape(-1)
-            res = kops.hot_gather(t, hot_rows, hot_ids, flat_idx)
+            if mesh is None:
+                res = kops.hot_gather(t, hot_rows, hot_ids, flat_idx)
+            else:
+                res = _sharded_hot_gather(t, hot_rows, hot_ids, flat_idx,
+                                          mesh, axes)
             out[f] = res.reshape(*idx.shape, *t.shape[1:])
         else:
             out[f] = jnp.take(t, idx, axis=0)
@@ -137,7 +163,10 @@ def _hot_cache(table_state, idx, fields, hot_keys_arr):
 
 
 def dispatch_lookup(plan, site_id: str, name: str, table_state, idx,
-                    fields, guards):
+                    fields, guards, mesh=None, axes=("data",)):
+    """Trace ``plan``'s implementation of one lookup site.  ``mesh`` /
+    ``axes`` (the sharded runtime's) place kernels that XLA cannot
+    partition on their own."""
     state = table_state[name]
     spec = plan.site(site_id) if plan is not None else None
     if spec is None or spec.impl in ("gather", "moe_fastpath",
@@ -184,7 +213,8 @@ def dispatch_lookup(plan, site_id: str, name: str, table_state, idx,
 
     if spec.impl == "hot_cache":
         fast = lambda: _hot_cache(state, idx, fields,
-                                  np.asarray(spec.hot_keys, np.int32))
+                                  np.asarray(spec.hot_keys, np.int32),
+                                  mesh, axes)
         if spec.guarded and guards is not None and name in guards:
             # RW site guard: fall back to the plain gather once the data
             # plane has written the table (deoptimization, §4.3.6)

@@ -64,12 +64,5 @@ class PlaneState:
         return jax.tree.map(jnp.copy, self)
 
 
-try:
-    jax.tree_util.register_dataclass(
-        PlaneState, data_fields=("tables", "instr", "guards"),
-        meta_fields=())
-except AttributeError:      # older JAX: manual registration
-    jax.tree_util.register_pytree_node(
-        PlaneState,
-        lambda s: ((s.tables, s.instr, s.guards), None),
-        lambda _, c: PlaneState(*c))
+jax.tree_util.register_dataclass(
+    PlaneState, data_fields=("tables", "instr", "guards"), meta_fields=())

@@ -1,45 +1,27 @@
-"""JAX API compatibility shims for the distributed layer.
+"""Thin wrappers over the distributed JAX API used across the repo.
 
-The repo targets a range of JAX releases:
-
-  * ``shard_map`` graduated from ``jax.experimental.shard_map`` (where
-    the replication-check kwarg is ``check_rep``) to ``jax.shard_map``
-    (where it is ``check_vma``);
-  * ``AbstractMesh`` changed its constructor from a single
-    ``((name, size), ...)`` shape tuple to separate
-    ``(axis_sizes, axis_names)`` arguments.
-
-All in-repo code goes through these wrappers instead of touching the
-moving targets directly.
+  * :func:`shard_map` — ``jax.shard_map`` with the replication check
+    off by default;
+  * :func:`abstract_mesh` — a device-free ``AbstractMesh`` from sizes and
+    names.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
-
-if hasattr(jax, "shard_map"):                      # JAX >= 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                              # JAX 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
     """``jax.shard_map`` with the replication check disabled by default
     (our bodies use collectives whose replication the checker cannot
-    prove), spelled identically on every supported JAX."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: check_vma})
+    prove)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def abstract_mesh(axis_sizes: Sequence[int],
                   axis_names: Sequence[str]):
-    """Device-free ``jax.sharding.AbstractMesh`` across constructor
-    generations."""
+    """Device-free ``jax.sharding.AbstractMesh``."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:                              # newer signature
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))

@@ -4,13 +4,20 @@ The JIT table specialization of §4.3.1, adapted to the TPU memory
 hierarchy: the heavy-hitter rows live in a VMEM-resident cache; cold keys
 DMA their row from the HBM table.  Mechanically:
 
-  * grid = (T,) with **scalar prefetch**: the per-query source row for the
-    HBM ref is precomputed (misses -> their row, hits -> row 0);
+  * grid = (T / tb, tb) with **scalar prefetch**: the per-query source
+    row for the HBM ref is precomputed (misses -> their row, hits -> row
+    0);
+  * the table is fetched in blocks of one sublane tile (8 rows), the
+    smallest slice the TPU's (8, 128) tiling allows, and the query's row
+    is selected inside the block — a dynamic row load the chip supports
+    for 32-bit data only, so on TPU the table must be 32-bit;
   * Pallas' pipelining elides the HBM DMA whenever the block index is
     unchanged between consecutive grid steps — so a run of hot hits costs
     ZERO HBM traffic after the first step (this is the x86 L1-inlined-code
     effect translated to DMA elision);
-  * the hit row is served from the VMEM cache (one dynamic VMEM load).
+  * the hit row is served from the VMEM cache (one dynamic VMEM load);
+  * each output block of ``tb`` rows stays resident in VMEM while its
+    ``tb`` queries write one row each, and is written back once.
 
 Numerics are exactly ``table[idx]`` — the cache is a verbatim copy — so
 no guard is needed for RO tables (the program-level guard covers
@@ -25,17 +32,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import ref as _ref
+_SUB = 8                 # rows per fetched table block (one tile)
+_MAX_OUT_ROWS = 256      # queries per resident output block
 
 
-def _kernel(row_sel_ref, hit_ref, pos_ref, table_row_ref, hot_rows_ref,
-            out_ref):
-    i = pl.program_id(0)
-    hit = hit_ref[i]
-    pos = pos_ref[i]
-    hot_row = hot_rows_ref[pos, :]
-    cold_row = table_row_ref[0, :]
-    out_ref[0, :] = jnp.where(hit > 0, hot_row, cold_row)
+def _kernel(row_sel_ref, hit_ref, pos_ref, table_ref, hot_rows_ref,
+            out_ref, *, tb: int):
+    j = pl.program_id(1)
+    i = pl.program_id(0) * tb + j
+    hot_row = hot_rows_ref[pl.ds(pos_ref[i], 1), :]
+    cold_row = table_ref[pl.ds(row_sel_ref[i] % _SUB, 1), :]
+    out_ref[pl.ds(j, 1), :] = jnp.where(hit_ref[i] > 0, hot_row, cold_row)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -46,28 +53,35 @@ def hot_gather_kernel(table: jax.Array, hot_rows: jax.Array,
     Returns (T, D) == table[idx]."""
     T = idx.shape[0]
     V, D = table.shape
+    tb = min(_MAX_OUT_ROWS, -(-T // 8) * 8)
+    t_pad = -(-T // tb) * tb
     match = idx[:, None] == hot_ids[None, :]
     hit = match.any(axis=1).astype(jnp.int32)
     pos = jnp.argmax(match, axis=1).astype(jnp.int32)
     # hits pin the HBM block index at row 0 => DMA elided on hit runs
     row_sel = jnp.where(hit > 0, 0, jnp.clip(idx, 0, V - 1)).astype(
         jnp.int32)
+    # padded queries re-read row 0 as hits would; their rows are dropped
+    pad = t_pad - T
+    row_sel, hit, pos = (jnp.pad(a, (0, pad)) for a in (row_sel, hit, pos))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(T,),
+        grid=(t_pad // tb, tb),
         in_specs=[
-            pl.BlockSpec((1, D),
-                         lambda i, row_sel, hit, pos: (row_sel[i], 0)),
+            pl.BlockSpec((_SUB, D),
+                         lambda c, j, row_sel, hit, pos:
+                         (row_sel[c * tb + j] // _SUB, 0)),
             pl.BlockSpec((hot_rows.shape[0], D),
-                         lambda i, row_sel, hit, pos: (0, 0)),
+                         lambda c, j, row_sel, hit, pos: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, D),
-                               lambda i, row_sel, hit, pos: (i, 0)),
+        out_specs=pl.BlockSpec((tb, D),
+                               lambda c, j, row_sel, hit, pos: (c, 0)),
     )
-    return pl.pallas_call(
-        _kernel,
+    out = pl.pallas_call(
+        functools.partial(_kernel, tb=tb),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, D), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad, D), table.dtype),
         interpret=interpret,
     )(row_sel, hit, pos, table, hot_rows)
+    return out[:T]

@@ -1,15 +1,15 @@
 """Public kernel entry points.
 
-Each op dispatches: Pallas TPU kernel when running on TPU and the shape is
-supported, otherwise the pure-jnp oracle from ``ref.py`` (bitwise the same
-semantics).  ``force`` overrides for testing: "kernel" | "ref" | "interpret".
+On TPU each op runs its Pallas kernel; a shape the kernel does not
+support raises (there is no silent fallback).  On every other backend it
+runs the pure-jnp oracle from ``ref.py`` (the same semantics).  ``force``
+overrides the choice for tests: "kernel" | "ref" | "interpret".
 """
 from __future__ import annotations
 
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
 from . import ref as _ref
 
@@ -45,7 +45,7 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int, init_state=None,
     return _ref.ssd_scan_ref(x, dt, A, Bm, Cm, chunk, init_state=init_state)
 
 
-def ssd_decode(x, dt, A, Bm, Cm, state, *, force: Optional[str] = None):
+def ssd_decode(x, dt, A, Bm, Cm, state):
     # single-token update is tiny — ref path everywhere
     return _ref.ssd_decode_ref(x, dt, A, Bm, Cm, state)
 
@@ -56,7 +56,3 @@ def hot_gather(table, hot_rows, hot_ids, idx, *, force: Optional[str] = None):
         return hot_gather_kernel(table, hot_rows, hot_ids, idx,
                                  interpret=(force == "interpret"))
     return _ref.hot_gather_ref(table, hot_rows, hot_ids, idx)
-
-
-def onehot_lookup(table, idx, *, force: Optional[str] = None):
-    return _ref.onehot_lookup_ref(table, idx)

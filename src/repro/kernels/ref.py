@@ -155,13 +155,3 @@ def hot_gather_ref(table: jax.Array, hot_rows: jax.Array, hot_ids: jax.Array,
     from_table = table[idx]
     return jnp.where(hit[:, None], from_hot, from_table)
 
-
-# ---------------------------------------------------------------------------
-# onehot_lookup oracle — small-table lookup as MXU matmul
-# ---------------------------------------------------------------------------
-
-def onehot_lookup_ref(table: jax.Array, idx: jax.Array) -> jax.Array:
-    """table: (V, D), idx: (T,) -> (T, D) via one-hot matmul (MXU-friendly
-    data-structure specialization for small V)."""
-    onehot = jax.nn.one_hot(idx, table.shape[0], dtype=table.dtype)
-    return onehot @ table

@@ -57,6 +57,7 @@ from ..serving import ServeConfig, build_fleet, build_params, \
     make_request_windows, make_serve_step
 from ..serving.frontend import FrontendConfig, OpenLoopDriver, \
     ServingFrontend, bursty_onoff_gaps, poisson_gaps
+from .compile_cache import enable_compile_cache
 
 
 def _skewed_params(cfg: ServeConfig, key, skew_router: bool):
@@ -143,16 +144,14 @@ def _drive_pipelined(step_one, make_batch, place, steps, fuse, inflight,
 def run_serve(steps=200, locality="high", morpheus=True,
               recompile_every=50, batch_size=8, skew_router=True,
               quiet=False, serve_cfg=None, features=None, mesh="auto",
-              xla_cache_dir=None, fuse=1, inflight=1):
+              fuse=1, inflight=1):
     """Drive the serving data plane for ``steps`` batches and return
     ``(stats, runtime)``.  ``mesh`` is "auto" (span all local devices,
     or single-device when there is only one), "none" (force
-    single-device), or a prebuilt ``jax.sharding.Mesh``.
-    ``xla_cache_dir`` points JAX's persistent compilation cache at a
-    directory so warm restarts skip ``t2`` for every executable a
-    previous process already built.  ``fuse=K`` serves K-step fused
-    windows through ``runtime.step_many``; ``inflight=N`` keeps up to N
-    dispatched units in flight instead of blocking per step."""
+    single-device), or a prebuilt ``jax.sharding.Mesh``.  ``fuse=K``
+    serves K-step fused windows through ``runtime.step_many``;
+    ``inflight=N`` keeps up to N dispatched units in flight instead of
+    blocking per step."""
     cfg = serve_cfg or ServeConfig()
     key = jax.random.PRNGKey(0)
     params = _skewed_params(cfg, key, skew_router)
@@ -168,8 +167,7 @@ def run_serve(steps=200, locality="high", morpheus=True,
         features=features or {"vision_enabled": False,
                               "track_sessions": True},
         moe_router_table="router",
-        mesh=mesh,
-        xla_cache_dir=xla_cache_dir)
+        mesh=mesh)
     rt = MorpheusRuntime(step_fn, tables, params,
                          make_synthetic_batch(cfg, key, batch_size),
                          cfg=ecfg, enable=morpheus)
@@ -256,8 +254,7 @@ def run_serve(steps=200, locality="high", morpheus=True,
 def run_controller_serve(planes=2, steps=200, locality="high",
                          recompile_every=50, batch_size=8,
                          skew_router=True, quiet=False, serve_cfg=None,
-                         workers=2, mesh="auto", xla_cache_dir=None,
-                         fuse=1, inflight=1):
+                         workers=2, mesh="auto", fuse=1, inflight=1):
     """One :class:`MorpheusController` driving ``planes`` data planes
     (distinct TableSets, per-plane traffic skew) from one process.
     Recompiles go through the controller's bounded worker pool
@@ -281,8 +278,7 @@ def run_controller_serve(planes=2, steps=200, locality="high",
         # identical step fn / schemas / shapes across the fleet: opt
         # every plane into FULL executable sharing in the controller's
         # cache — the generic executable is compiled once, not N times
-        cache_ns="serve-fleet",
-        xla_cache_dir=xla_cache_dir)
+        cache_ns="serve-fleet")
     rts = []
     for p, (step_fn, tables) in enumerate(
             build_fleet(cfg, key, planes)):
@@ -412,8 +408,8 @@ def run_frontend_serve(planes=1, requests=600, rate=150.0,
                        max_wait_ms=2.0, queue_cap=512, window_k_max=4,
                        inflight=2, recompile_every_s=0.25,
                        locality="high", skew_router=True, quiet=False,
-                       serve_cfg=None, mesh="auto", workers=2,
-                       xla_cache_dir=None, seed=0, keep_outputs=False):
+                       serve_cfg=None, mesh="auto", workers=2, seed=0,
+                       keep_outputs=False, ladder=None):
     """Request-level serving: open-loop synthetic arrivals (Poisson or
     bursty ON/OFF at ``rate`` req/s) through one
     :class:`~repro.serving.frontend.ServingFrontend` per plane, all
@@ -421,9 +417,12 @@ def run_frontend_serve(planes=1, requests=600, rate=150.0,
     end in-process: arrivals -> admission -> dynamic batching -> fused
     ``step_many`` dispatch -> arrival-profile snapshot -> recompile ->
     BatchShapePass bucket/K selection -> (on drift) program-guard deopt.
+    ``ladder`` is the pad-bucket ladder (None: powers of two up to
+    ``batch_size``); every bucket is compiled before the trace starts.
 
     Returns ``(stats, controller, runtimes, frontends)`` — ``stats``
-    carries per-plane AND fleet-level SLO attainment."""
+    carries per-plane AND fleet-level SLO attainment; the submitted
+    requests are in ``stats["request_objs"]``."""
     cfg = serve_cfg or ServeConfig()
     key = jax.random.PRNGKey(seed)
     params = _skewed_params(cfg, key, skew_router)
@@ -433,11 +432,13 @@ def run_frontend_serve(planes=1, requests=600, rate=150.0,
         mesh = None
     controller = MorpheusController(ControllerConfig(workers=workers))
     ecfg_kw = dict(
-        sketch=SketchConfig(sample_every=4, max_hot=4, hot_coverage=0.8),
+        # 32 hot rows: the "high" locality trace draws its tokens from 32
+        # ids, so a cache that size can cover it and claim hot_cache
+        sketch=SketchConfig(sample_every=4, max_hot=32, hot_coverage=0.8),
         moe_router_table="router",
-        mesh=mesh, cache_ns="serve-fleet",
-        xla_cache_dir=xla_cache_dir)
+        mesh=mesh, cache_ns="serve-fleet")
     fcfg = FrontendConfig(capacity=queue_cap, max_batch=batch_size,
+                          ladder=ladder,
                           max_wait_s=max_wait_ms * 1e-3,
                           window_k_max=window_k_max, inflight=inflight,
                           default_slo_s=slo_ms * 1e-3)
@@ -522,6 +523,7 @@ def run_frontend_serve(planes=1, requests=600, rate=150.0,
         "p50_ms": fleet_hist.quantile(0.50) * 1e3,
         "p99_ms": fleet_hist.quantile(0.99) * 1e3,
         "per_plane": per_plane,
+        "request_objs": driver.requests,
     }
     if not quiet:
         for pid, ps in per_plane.items():
@@ -545,7 +547,45 @@ def run_frontend_serve(planes=1, requests=600, rate=150.0,
     return stats, controller, rts, frontends
 
 
+def serving_failures(controller) -> list:
+    """Why a serve run must not count as a success, one line each (empty
+    when it may): a recompile that failed or was given up, a quarantined
+    plan signature, a plane left degraded or quarantined, a dispatch
+    fault, or a failed background warm.  Any of these means traffic was
+    served by generic code that the specialized plan should have
+    replaced."""
+    cs = controller.stats()
+    sch = cs.scheduler
+    out = []
+    if sch["failed"] or sch["gave_up"]:
+        out.append(f"recompiles failed={sch['failed']} "
+                   f"gave_up={sch['gave_up']} "
+                   f"last_errors={sch['last_errors']}")
+    if cs.cache.quarantined:
+        out.append(f"{cs.cache.quarantined} plan signature(s) "
+                   f"quarantined")
+    for pid, h in cs.health.items():
+        if h["state"] in ("degraded", "quarantined"):
+            out.append(f"{pid} ended {h['state']}: {h['last_fault']}")
+    if cs.totals.get("faults", 0):
+        out.append(f"faults={cs.totals['faults']}")
+    for pid, ps in cs.planes.items():
+        out.extend(f"{pid} warm failed: {err}" for err in ps["warm_errors"])
+    return out
+
+
+def _finish(controller) -> int:
+    """Close the controller; print the run's failures and return the
+    exit code (1 when there were any)."""
+    failures = serving_failures(controller)
+    controller.close()
+    for line in failures:
+        print(f"[serve] FAILED: {line}", file=sys.stderr)
+    return 1 if failures else 0
+
+
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--locality", default="high",
@@ -564,10 +604,6 @@ def main(argv=None) -> int:
                          "fleet even for a single plane")
     ap.add_argument("--workers", type=int, default=2,
                     help="controller recompile worker pool size")
-    ap.add_argument("--xla-cache-dir", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache directory — "
-                         "warm restarts skip t2 for executables already "
-                         "built by a previous process")
     ap.add_argument("--fuse", type=int, default=1, metavar="K",
                     help="serve K-step lax.scan-fused windows "
                          "(runtime.step_many) — one Python dispatch per "
@@ -614,10 +650,8 @@ def main(argv=None) -> int:
             arrival=args.arrival, batch_size=args.batch_size,
             slo_ms=args.slo_ms, max_wait_ms=args.max_wait_ms,
             queue_cap=args.queue_cap, inflight=args.inflight,
-            mesh=args.mesh, workers=args.workers,
-            xla_cache_dir=args.xla_cache_dir)
-        controller.close()
-        return 0
+            mesh=args.mesh, workers=args.workers)
+        return _finish(controller)
     if args.planes > 1 or args.controller:
         if args.no_morpheus:
             print("[serve] --no-morpheus is a single-plane baseline "
@@ -629,18 +663,16 @@ def main(argv=None) -> int:
             locality=args.locality,
             recompile_every=args.recompile_every,
             batch_size=args.batch_size, workers=args.workers,
-            mesh=args.mesh, xla_cache_dir=args.xla_cache_dir,
-            fuse=args.fuse, inflight=args.inflight)
-        controller.close()
-        return 0
+            mesh=args.mesh, fuse=args.fuse, inflight=args.inflight)
+        return _finish(controller)
     _, rt = run_serve(steps=args.steps, locality=args.locality,
                       morpheus=not args.no_morpheus,
                       recompile_every=args.recompile_every,
                       batch_size=args.batch_size, mesh=args.mesh,
-                      xla_cache_dir=args.xla_cache_dir,
                       fuse=args.fuse, inflight=args.inflight)
+    rc = _finish(rt.controller)
     rt.close()
-    return 0
+    return rc
 
 
 if __name__ == "__main__":

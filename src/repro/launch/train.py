@@ -30,6 +30,8 @@ Examples:
     python -m repro.launch.train --arch llama3-8b --smoke --steps 50
     python -m repro.launch.train --arch phi3.5-moe-42b-a6.6b --smoke \
         --steps 40 --fail-at-step 25 --resume   # crash + recover
+    python -m repro.launch.train --arch starcoder2-3b --layers 4 \
+        --seq 2048 --batch 1 --steps 3          # full width, 4 layers
 """
 from __future__ import annotations
 
@@ -51,6 +53,7 @@ from ..models import Model, unzip
 from ..models.params import zip_axes
 from ..optim import AdamWConfig, init_opt_state
 from ..training import SupervisorConfig, TrainSupervisor
+from .compile_cache import enable_compile_cache
 
 
 def build_state(model: Model, key, abstract=False):
@@ -62,11 +65,14 @@ def build_state(model: Model, key, abstract=False):
             {"params": params_axes, "opt": opt_axes})
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the model's depth to N layers, every width "
+                    "as configured")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -98,11 +104,18 @@ def main(argv=None) -> int:
                     "re-plan hot experts from router statistics and swap "
                     "in the branch-injected train step (0 = off)")
     ap.add_argument("--hot-coverage", type=float, default=0.95)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def train(args: argparse.Namespace) -> dict:
+    """Run the training loop ``args`` describes.  Returns ``rc`` (0 ok,
+    2 on a non-finite loss), the last ``loss``, the supervisor's
+    ``stats`` and ``n_params``."""
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
     model = Model(cfg)
     key = jax.random.PRNGKey(args.seed)
 
@@ -160,6 +173,7 @@ def main(argv=None) -> int:
 
     pending = None
     rc = 0
+    loss = float("nan")
     try:
         for step in range(start_step, args.steps):
             # process-crash injection: escapes the driver (the
@@ -191,7 +205,8 @@ def main(argv=None) -> int:
                       flush=True)
             if not np.isfinite(loss):
                 print("[train] non-finite loss — aborting", flush=True)
-                return 2
+                rc = 2
+                break
             if args.ckpt_every and (step + 1) % args.ckpt_every == 0:
                 if pending is not None:
                     pending.join()       # surface async write errors
@@ -205,7 +220,8 @@ def main(argv=None) -> int:
         if pending is not None:
             pending.join()               # re-raises write failures —
             pending = None               # a lost checkpoint fails loudly
-        print(f"[train] done at step {args.steps}", flush=True)
+        if rc == 0:
+            print(f"[train] done at step {args.steps}", flush=True)
     finally:
         if pending is not None:
             try:
@@ -213,8 +229,15 @@ def main(argv=None) -> int:
             except Exception as e:       # noqa: BLE001 — already failing
                 print(f"[train] async checkpoint write failed: {e}",
                       flush=True)
+        stats = sup.stats()
         sup.close()
-    return rc
+    return {"rc": rc, "loss": loss, "stats": stats, "n_params": n_params}
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    return train(args)["rc"]
 
 
 if __name__ == "__main__":

@@ -147,7 +147,7 @@ def make_serve_step(cfg: ServeConfig):
         q = x @ lp["wq"]
         k = x @ lp["wk"]
         v = x @ lp["wv"]
-        H = 4
+        H = cfg.n_heads
         hd = D // H
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, H, hd)

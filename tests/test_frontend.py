@@ -462,9 +462,12 @@ def test_step_many_serves_bucket_shapes_at_any_k():
         out1 = np.asarray(rt.step_many([b]))     # K=1, bucket structure
         assert out1.shape[0] == 1
         np.testing.assert_array_equal(out1[0], ref)
-        out2 = np.asarray(rt.step_many([b, b]))  # K=2 fused window
-        np.testing.assert_array_equal(out2[0], ref)
-        np.testing.assert_array_equal(out2[1], ref)
+        # K=2 fused window: the step runs as a lax.scan body, which XLA
+        # fuses and orders differently from the standalone step, so
+        # float32 sums may round differently (a few ulps, not bitwise)
+        out2 = np.asarray(rt.step_many([b, b]))
+        np.testing.assert_allclose(out2[0], ref, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(out2[1], ref, rtol=1e-5, atol=1e-5)
     finally:
         rt.close()
 

@@ -461,6 +461,54 @@ def test_compile_fault_retry_exhaustion_quarantines_signature():
         ctl.close()
 
 
+def test_serving_failures_report_give_up_and_quarantine():
+    """`launch/serve.py`'s exit check: a healthy run reports nothing; a
+    recompile that gave up (signature quarantined, plane QUARANTINED) is
+    reported with the scheduler's last error."""
+    from repro.launch.serve import serving_failures
+
+    ctl = _chaos_controller(max_retries=0)
+    rt = _mk(controller=ctl)
+    try:
+        _warm(rt)
+        assert serving_failures(ctl) == []
+        rt.arm_compile_faults(1)
+        ctl.schedule(rt)
+        assert ctl.drain(timeout=60.0)
+        report = "\n".join(serving_failures(ctl))
+        assert "gave_up=1" in report
+        assert "SimulatedCompileFailure" in report
+        assert "quarantined" in report
+    finally:
+        rt.close()
+        ctl.close()
+
+
+def test_failed_background_warm_is_counted_and_reported(monkeypatch):
+    """A background fused-generic warm that fails is no longer swallowed:
+    its error is kept, and `launch/serve.py`'s check sees it."""
+    from repro.launch.serve import serving_failures
+
+    ctl = MorpheusController()
+    rt = _mk(controller=ctl)
+    try:
+        _warm(rt)
+
+        def boom(*a, **kw):
+            raise RuntimeError("compiler refused")
+        monkeypatch.setattr(rt, "_compile_into_cache", boom)
+        avals = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((2,) + x.shape, x.dtype),
+            _batch())
+        rt._warm_fused_generic(avals, 2)
+        assert rt.stats.warm_errors == ["RuntimeError: compiler refused"]
+        assert any("compiler refused" in line
+                   for line in serving_failures(ctl))
+    finally:
+        rt.close()
+        ctl.close()
+
+
 # ---------------------------------------------------------------------------
 # frontend: explicit rejection + window-fault accounting
 # ---------------------------------------------------------------------------

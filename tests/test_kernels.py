@@ -91,6 +91,31 @@ def test_ssd_scan_vs_oracle(B, S, H, P, N, chunk, hblk, dtype):
                                rtol=1e-3, atol=1e-3)
 
 
+def test_ssd_scan_kernel_grad_matches_oracle():
+    """The kernel is differentiable, and its gradients are the oracle's
+    (the backward pass is the reference scan's VJP)."""
+    ks = jax.random.split(KEY, 5)
+    B, S, H, P, N = 1, 40, 4, 8, 16
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5)
+    Bm = jax.random.normal(ks[3], (B, S, 1, N)) * 0.3
+    Cm = jax.random.normal(ks[4], (B, S, 1, N)) * 0.3
+
+    def loss(scan, *args):
+        y, fin = scan(*args)
+        return jnp.sum(y ** 2) + jnp.sum(fin ** 2)
+    kernel = lambda *a: ssd_scan_kernel(*a, chunk=16, hblk=2,
+                                        interpret=True)
+    oracle = lambda *a: R.ssd_scan_ref(*a, 16)
+    argnums = (1, 2, 3, 4, 5)
+    g = jax.grad(loss, argnums)(kernel, x, dt, A, Bm, Cm)
+    gr = jax.grad(loss, argnums)(oracle, x, dt, A, Bm, Cm)
+    for a, b in zip(g, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
 def test_ssd_scan_chunk_invariance():
     """The chunk size is a tiling choice — results must not depend on it."""
     ks = jax.random.split(KEY, 5)
