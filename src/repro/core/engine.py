@@ -40,6 +40,7 @@ from .specialize import GENERIC_PLAN, SpecializationPlan
 from .state import PlaneState
 from .tables import TableSet, analysis_sites, analyzing, \
     reset_site_counters
+from .tracing import span
 
 
 @dataclass
@@ -205,7 +206,7 @@ class MorpheusEngine:
 
         Returns ``(plan, t1_seconds, pass_stats)``."""
         assert self._analyzed
-        t0 = time.time()
+        t0 = time.perf_counter()
         if snapshot is None:
             # read the version BEFORE copying: an update racing in
             # between then makes the plan look stale (spurious deopt,
@@ -240,7 +241,7 @@ class MorpheusEngine:
             instrumented=instrumented,
             label="specialized" + ("+instr" if instrumented else ""),
         )
-        return plan, time.time() - t0, dict(draft.stats)
+        return plan, time.perf_counter() - t0, dict(draft.stats)
 
     def generic_plan(self, instrumented: bool = False) -> SpecializationPlan:
         """The unspecialized plan (every site generic, no flags pinned)
@@ -349,7 +350,8 @@ class MorpheusEngine:
     def compile(self, plan: SpecializationPlan, params, state: PlaneState,
                 batch, *, donate: Optional[bool] = None,
                 in_shardings=None, out_shardings=None,
-                fuse: Optional[int] = None
+                fuse: Optional[int] = None,
+                cycle: Optional[int] = None
                 ) -> Tuple[Callable, float]:
         """AOT-compile ``plan`` into an executable; returns
         ``(executable, t2_seconds)`` where the executable is called as
@@ -363,12 +365,18 @@ class MorpheusEngine:
         ``jax.jit`` (prefix pytrees over ``(params, state, batch)`` / the
         ``(out, state)`` result) for per-leaf placement; when the engine
         has a mesh and neither is given, :meth:`default_shardings`
-        supplies the sharded-serving placement."""
-        t0 = time.time()
-        lowered = self.lower(plan, params, state, batch, donate=donate,
-                             in_shardings=in_shardings,
-                             out_shardings=out_shardings, fuse=fuse)
-        compiled = lowered.compile()
-        with self._count_lock:
-            self.compile_count += 1
-        return compiled, time.time() - t0
+        supplies the sharded-serving placement.  ``cycle``, the ordinal
+        of the recompile cycle that asked (None outside one), tags the
+        ``morpheus.engine.compile`` span as its stat ``n``."""
+        stats = {"fuse": fuse or 0}
+        if cycle is not None:
+            stats["n"] = cycle
+        with span("engine.compile", **stats):
+            t0 = time.perf_counter()
+            lowered = self.lower(plan, params, state, batch, donate=donate,
+                                 in_shardings=in_shardings,
+                                 out_shardings=out_shardings, fuse=fuse)
+            compiled = lowered.compile()
+            with self._count_lock:
+                self.compile_count += 1
+            return compiled, time.perf_counter() - t0

@@ -149,6 +149,7 @@ from .snapshot import TableSnapshotWorker, VersionedSnapshot
 from .specialize import SpecializationPlan
 from .state import PlaneState
 from .tables import TableSet
+from .tracing import install_gc_spans, span
 
 
 @dataclass
@@ -339,6 +340,7 @@ class MorpheusRuntime:
                  exec_cache: Optional[ExecutableCache] = None,
                  controller: Optional[MorpheusController] = None,
                  plane_id: Optional[str] = None):
+        install_gc_spans()
         self.engine = MorpheusEngine(user_step, tables, cfg)
         self.tables = tables
         self.enable = enable
@@ -415,6 +417,10 @@ class MorpheusRuntime:
         self._warm_threads: List[threading.Thread] = []
         self._recompile_mutex = threading.Lock()
         self._compiling = False
+        # recompile-cycle ordinal, and the one the current thread is
+        # running (read by _compile_into_cache to tag engine.compile)
+        self._cycle_seq = 0
+        self._cycle_local = threading.local()
         self._queued: List[tuple] = []
         self._closed = False
         self._merge_fn: Optional[Callable] = None
@@ -565,10 +571,12 @@ class MorpheusRuntime:
         :meth:`step_many` consumes.  Already-resident arrays pass
         through untouched, so prefetching — or re-stepping — the same
         placed batch performs zero transfers."""
-        if fused and isinstance(batch, (list, tuple)):
-            batch = stack_batches(batch)
         count: dict = {}
-        placed = self._place_batch(batch, stacked=fused, count=count)
+        with span("runtime.place") as s:
+            if fused and isinstance(batch, (list, tuple)):
+                batch = stack_batches(batch)
+            placed = self._place_batch(batch, stacked=fused, count=count)
+            s.set_metadata(transfers=count.get("transfers", 0))
         if count:
             self.stats.bump(batch_transfers=count["transfers"])
         return placed
@@ -675,6 +683,7 @@ class MorpheusRuntime:
         and cache counters untouched — they describe the Morpheus cycle,
         not oracle traffic (the cache's own ``stats`` always count)."""
         results: List[Any] = [None] * len(plans)
+        cycle = getattr(self._cycle_local, "n", None)
 
         def compile_one(i: int, plan: SpecializationPlan, donate: bool):
             key = self._exec_key(plan, batch, donate, instr_struct,
@@ -683,7 +692,7 @@ class MorpheusRuntime:
                 results[i] = ("ok", self.exec_cache.get_or_compile(
                     key, lambda: self.engine.compile(
                         plan, self.params, state, batch, donate=donate,
-                        fuse=fuse)))
+                        fuse=fuse, cycle=cycle)))
             except BaseException as e:          # re-raised on the caller
                 results[i] = ("err", e)
 
@@ -827,44 +836,51 @@ class MorpheusRuntime:
         brackets it with two brief critical sections (see module
         docstring), so the control plane and other planes' recompiles
         never serialize behind device execution."""
-        cnt: dict = {}
-        batch = self._place_batch(batch, count=cnt)
-        gen, active, state = self._begin_step()
-        plan, spec_exec, instr_exec, generic_exec = active
-        sampled = False
-        deltas = {"steps": 1}
-        if cnt:
-            deltas["batch_transfers"] = cnt["transfers"]
-        # degraded-mode check first, then the program-level guard (ONE
-        # host compare covering every RO table): a faulted plane serves
-        # generic-only until a re-specialization cycle clears the flag
-        if self._degraded:
-            exec_ = generic_exec
-            deltas["degraded_steps"] = 1
-        elif self.tables.version != plan.version:
-            exec_ = generic_exec
-            deltas["deopt_steps"] = 1
-        elif self.enable and self.sampler.should_sample(self._step_seq):
-            exec_ = instr_exec
-            sampled = True
-            deltas["instr_steps"] = 1
-        else:
-            exec_ = spec_exec
-        try:
-            # the chaos hook fires BEFORE the executable runs: the state
-            # tuple is not donated yet, so the abort below leaves the
-            # plane's state intact and the same batch can be retried
-            # through the degraded (generic) path — byte-identically
-            if self._fault_injector is not None:
-                self._fault_injector.check(self._step_seq)
-            out, new_state = exec_(self.params, state, batch)
-        except BaseException as e:
-            self._abort_step()
-            if isinstance(e, Exception):
-                self._on_step_fault(e)
-            raise
-        self._commit_step(gen, new_state, sampled, deltas)
-        return out
+        with span("runtime.step", k=1):
+            cnt: dict = {}
+            batch = self._place_batch(batch, count=cnt)
+            with span("runtime.claim"):
+                gen, active, state = self._begin_step()
+            plan, spec_exec, instr_exec, generic_exec = active
+            sampled = False
+            deltas = {"steps": 1}
+            if cnt:
+                deltas["batch_transfers"] = cnt["transfers"]
+            # degraded-mode check first, then the program-level guard
+            # (ONE host compare covering every RO table): a faulted plane
+            # serves generic-only until a re-specialization cycle clears
+            # the flag
+            if self._degraded:
+                exec_, role = generic_exec, "degraded"
+                deltas["degraded_steps"] = 1
+            elif self.tables.version != plan.version:
+                exec_, role = generic_exec, "generic"
+                deltas["deopt_steps"] = 1
+            elif self.enable and self.sampler.should_sample(
+                    self._step_seq):
+                exec_, role = instr_exec, "instr"
+                sampled = True
+                deltas["instr_steps"] = 1
+            else:
+                exec_, role = spec_exec, "spec"
+            try:
+                # the chaos hook fires BEFORE the executable runs: the
+                # state tuple is not donated yet, so the abort below
+                # leaves the plane's state intact and the same batch can
+                # be retried through the degraded (generic) path —
+                # byte-identically
+                with span("runtime.launch", role=role):
+                    if self._fault_injector is not None:
+                        self._fault_injector.check(self._step_seq)
+                    out, new_state = exec_(self.params, state, batch)
+            except BaseException as e:
+                self._abort_step()
+                if isinstance(e, Exception):
+                    self._on_step_fault(e)
+                raise
+            with span("runtime.commit"):
+                self._commit_step(gen, new_state, sampled, deltas)
+            return out
 
     def step_many(self, batches, k: Optional[int] = None):
         """Run a fused window of K serving steps through ONE
@@ -885,6 +901,11 @@ class MorpheusRuntime:
         landing mid-window is queued and drained at the window's commit,
         so the *next* window deopts (§4.4 semantics at window
         granularity, byte-identical outputs to K=1 stepping)."""
+        n = len(batches) if isinstance(batches, (list, tuple)) else k
+        with span("runtime.step_many", k=n or 0):
+            return self._step_many(batches, k)
+
+    def _step_many(self, batches, k: Optional[int]):
         if isinstance(batches, (list, tuple)):
             if k is not None and k != len(batches):
                 raise ValueError(
@@ -925,36 +946,46 @@ class MorpheusRuntime:
             # observe (and both instrument) the same ordinal
             self._window_seq += 1
             window = self._window_seq
+        retries = -1
         while True:
+            retries += 1
             # prepare OUTSIDE any lock: read the active world, pick the
             # window's role, and fetch (possibly compile) its fused
             # executable — then claim with generation validation and
             # retry if a writer landed in between.
-            gen = self._gen
-            plan = self._active[0]
-            isites = self._active_isites
-            deltas = {"steps": k}
-            if cnt:
-                deltas["batch_transfers"] = cnt["transfers"]
-            sampled = False
-            if self._degraded:
-                # safe to read lock-free here: the flag only flips under
-                # _write(), which bumps the generation — a stale read is
-                # caught by the claim validation below and retried
-                role_plan = self.generic_plan
-                deltas["degraded_steps"] = k
-            elif self.tables.version != plan.version:
-                role_plan = self.generic_plan
-                deltas["deopt_steps"] = k
-            elif (self.enable and self.sampler.should_sample_window(
-                    window, k)):
-                role_plan = self._instr_twin(plan, isites)
-                sampled = True
-                deltas["instr_steps"] = k
-            else:
-                role_plan = plan
-            fexec, mkey = self._fused_exec(role_plan, stacked, isites, k)
-            claim = self._begin_step(expect_gen=gen)
+            with span("runtime.prepare"):
+                gen = self._gen
+                plan = self._active[0]
+                isites = self._active_isites
+                deltas = {"steps": k}
+                if cnt:
+                    deltas["batch_transfers"] = cnt["transfers"]
+                sampled = False
+                if self._degraded:
+                    # safe to read lock-free here: the flag only flips
+                    # under _write(), which bumps the generation — a
+                    # stale read is caught by the claim validation below
+                    # and retried
+                    role_plan, role = self.generic_plan, "degraded"
+                    deltas["degraded_steps"] = k
+                elif self.tables.version != plan.version:
+                    role_plan, role = self.generic_plan, "generic"
+                    deltas["deopt_steps"] = k
+                elif (self.enable and self.sampler.should_sample_window(
+                        window, k)):
+                    role_plan = self._instr_twin(plan, isites)
+                    role = "instr"
+                    sampled = True
+                    deltas["instr_steps"] = k
+                else:
+                    role_plan, role = plan, "spec"
+                fexec, mkey = self._fused_exec(role_plan, stacked, isites,
+                                               k)
+                # an executable taken from the memo is the memo's object
+                memo = "hit" if self._fused_memo.get(mkey) is fexec \
+                    else "miss"
+            with span("runtime.claim"):
+                claim = self._begin_step(expect_gen=gen)
             if claim is not None:
                 break
         gen, _, state = claim
@@ -967,15 +998,18 @@ class MorpheusRuntime:
         try:
             # same fault-boundary contract as step(): the chaos hook
             # fires before the executable, so the abort is state-safe
-            if self._fault_injector is not None:
-                self._fault_injector.check(self._step_seq)
-            out, new_state = fexec(self.params, state, stacked)
+            with span("runtime.launch", role=role, memo=memo,
+                      retries=retries):
+                if self._fault_injector is not None:
+                    self._fault_injector.check(self._step_seq)
+                out, new_state = fexec(self.params, state, stacked)
         except BaseException as e:
             self._abort_step()
             if isinstance(e, Exception):
                 self._on_step_fault(e)
             raise
-        self._commit_step(gen, new_state, sampled, deltas)
+        with span("runtime.commit"):
+            self._commit_step(gen, new_state, sampled, deltas)
         return out
 
     def warm_fused(self, batches, k: Optional[int] = None) -> None:
@@ -1424,7 +1458,14 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
         # _active/_active_isites below safe (the only other writer is
         # another cycle).
         with self._recompile_mutex:
-            return self._recompile_cycle()
+            self._cycle_seq += 1
+            n = self._cycle_seq
+            self._cycle_local.n = n
+            try:
+                with span("cycle", plane=str(self.plane_id), n=n):
+                    return self._recompile_cycle()
+            finally:
+                self._cycle_local.n = None
 
     def _recompile_cycle(self) -> dict:
         with self._cond:
@@ -1437,10 +1478,11 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
             # the profile retained at the last armed cycle — dropping it
             # would lose every traffic-dependent fast path and make the
             # signature oscillate.
-            snap = self.snapshot_worker.get(self.tables.version)
+            with span("cycle.snapshot"):
+                snap = self.snapshot_worker.get(self.tables.version)
+                instr = self._host_instr_snapshot()
             self.last_snapshot = snap
             self.stats.log("snapshot_versions", snap.version)
-            instr = self._host_instr_snapshot()
             if self.sampler.armed and _instr_has_samples(instr):
                 self._plan_instr = instr
             else:
@@ -1459,9 +1501,10 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
                 from .passes.batch_shape import plan_batch_shape
                 profile["prev_shape"] = \
                     plan_batch_shape(self._active[0])
-            plan, t1, pass_stats = self.engine.build_plan(
-                instr, snapshot=snap.tables, version=snap.version,
-                profile=profile)
+            with span("cycle.plan"):
+                plan, t1, pass_stats = self.engine.build_plan(
+                    instr, snapshot=snap.tables, version=snap.version,
+                    profile=profile)
             self.stats.log("t1_history", t1)
             self.stats.pass_stats = pass_stats
             # recorded BEFORE any failure below: the scheduler's give-up
@@ -1513,7 +1556,7 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
                 fresh_instr, fresh_guards = \
                     self._fresh_instr_guards(isites)
                 recovered = False
-                with self._write():
+                with span("cycle.revalidate"), self._write():
                     self._active = (
                         dataclasses.replace(active_plan,
                                             version=plan.version),
@@ -1545,28 +1588,29 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
                 # compiled in the SAME concurrent batch as the twins
                 wanted += [self.generic_plan,
                            self._instr_twin(self.generic_plan, isites)]
-            execs = self._get_many(wanted, self._example_batch, isites)
-            # precompile the fused variants for every window structure
-            # step_many has served (specialized + twin, and the generic
-            # deopt target on a topology change): still on the recompile
-            # thread, concurrently per miss — a post-swap fused window
-            # must hit the cache, not stall serving on an inline t2
-            with self._cond:     # step_many registers entries under it
-                fused_shapes = list(self._fused_shapes.items())
-            # ... and for the window shapes the NEW plan itself induces
-            # (BatchShapePass bucket/K selection): the swap must land
-            # with every shape the batcher will now form already
-            # compiled, not just the shapes traffic happened to show
-            done = {sk for sk, _ in fused_shapes}
-            for sk, avals in _induced_window_avals(plan, fused_shapes):
-                if sk not in done:
-                    done.add(sk)
-                    fused_shapes.append((sk, avals))
-            for (bk, k), avals in fused_shapes:
-                fused_wanted = [plan, self._instr_twin(plan, isites)]
-                if isites != self._active_isites:
-                    fused_wanted.append(self.generic_plan)
-                self._get_many(fused_wanted, avals, isites, fuse=k)
+            with span("cycle.compile"):
+                execs = self._get_many(wanted, self._example_batch, isites)
+                # precompile the fused variants for every window structure
+                # step_many has served (specialized + twin, and the generic
+                # deopt target on a topology change): still on the recompile
+                # thread, concurrently per miss — a post-swap fused window
+                # must hit the cache, not stall serving on an inline t2
+                with self._cond:     # step_many registers entries under it
+                    fused_shapes = list(self._fused_shapes.items())
+                # ... and for the window shapes the NEW plan itself induces
+                # (BatchShapePass bucket/K selection): the swap must land
+                # with every shape the batcher will now form already
+                # compiled, not just the shapes traffic happened to show
+                done = {sk for sk, _ in fused_shapes}
+                for sk, avals in _induced_window_avals(plan, fused_shapes):
+                    if sk not in done:
+                        done.add(sk)
+                        fused_shapes.append((sk, avals))
+                for (bk, k), avals in fused_shapes:
+                    fused_wanted = [plan, self._instr_twin(plan, isites)]
+                    if isites != self._active_isites:
+                        fused_wanted.append(self.generic_plan)
+                    self._get_many(fused_wanted, avals, isites, fuse=k)
             new_exec, new_instr_exec = execs[0], execs[1]
             new_generic = (execs[2] if len(execs) > 2
                            else active_generic)
@@ -1577,9 +1621,9 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
             # copy fn traced, on a structure change) outside the lock
             fresh_instr, fresh_guards = self._fresh_instr_guards(isites)
             self._backbuf.publish(fresh_instr)
-            t0 = time.time()
+            t0 = time.perf_counter()
             recovered = False
-            with self._write():
+            with span("cycle.swap"), self._write():
                 # ATOMIC swap (the BPF_PROG_ARRAY pointer update): one
                 # reference assignment replaces the whole tuple — after
                 # quiescing the in-flight step, since the state reset
@@ -1600,7 +1644,7 @@ FailureInjector`): its ``check(step)`` runs inside every step/window's
                     self._degraded = False      # plane has re-specialized
                     self._degrade_reason = None
                     recovered = True
-            self.stats.log("swap_history", time.time() - t0)
+            self.stats.log("swap_history", time.perf_counter() - t0)
             deltas = {"recompiles": 1, "swaps": 1}
             if recovered:
                 deltas["recoveries"] = 1

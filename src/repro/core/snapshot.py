@@ -38,6 +38,7 @@ from dataclasses import dataclass
 from typing import Dict, Optional
 
 from .tables import Table, TableSet
+from .tracing import span
 
 
 @dataclass(frozen=True)
@@ -80,7 +81,10 @@ class TableSnapshotWorker:
 
     # ---- worker side ------------------------------------------------------
     def _take(self) -> VersionedSnapshot:
-        version, tabs = self._tables.cow_snapshot()
+        # the version read here is the one the copy will see unless an
+        # update races in between (then the copy is newer, never older)
+        with span("snapshot.copy", version=self._tables.version):
+            version, tabs = self._tables.cow_snapshot()
         return VersionedSnapshot(version, tabs, threading.get_ident(),
                                  threading.current_thread().name)
 

@@ -151,11 +151,12 @@ def _hot_cache(table_state, idx, fields, hot_keys_arr, mesh=None,
         if t.ndim >= 2 and jnp.issubdtype(t.dtype, jnp.floating):
             hot_rows = jnp.take(t, hot_ids, axis=0)
             flat_idx = idx.reshape(-1)
-            if mesh is None:
-                res = kops.hot_gather(t, hot_rows, hot_ids, flat_idx)
-            else:
-                res = _sharded_hot_gather(t, hot_rows, hot_ids, flat_idx,
-                                          mesh, axes)
+            with jax.named_scope("hot_gather"):
+                if mesh is None:
+                    res = kops.hot_gather(t, hot_rows, hot_ids, flat_idx)
+                else:
+                    res = _sharded_hot_gather(t, hot_rows, hot_ids,
+                                              flat_idx, mesh, axes)
             out[f] = res.reshape(*idx.shape, *t.shape[1:])
         else:
             out[f] = jnp.take(t, idx, axis=0)
@@ -164,9 +165,17 @@ def _hot_cache(table_state, idx, fields, hot_keys_arr, mesh=None,
 
 def dispatch_lookup(plan, site_id: str, name: str, table_state, idx,
                     fields, guards, mesh=None, axes=("data",)):
-    """Trace ``plan``'s implementation of one lookup site.  ``mesh`` /
-    ``axes`` (the sharded runtime's) place kernels that XLA cannot
-    partition on their own."""
+    """Trace ``plan``'s implementation of one lookup site, its device ops
+    under the named scope ``tables.<name>``.  ``mesh`` / ``axes`` (the
+    sharded runtime's) place kernels that XLA cannot partition on their
+    own."""
+    with jax.named_scope(f"tables.{name}"):
+        return _dispatch_lookup(plan, site_id, name, table_state, idx,
+                                fields, guards, mesh, axes)
+
+
+def _dispatch_lookup(plan, site_id, name, table_state, idx, fields,
+                     guards, mesh, axes):
     state = table_state[name]
     spec = plan.site(site_id) if plan is not None else None
     if spec is None or spec.impl in ("gather", "moe_fastpath",

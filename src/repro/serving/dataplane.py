@@ -169,19 +169,25 @@ def make_serve_step(cfg: ServeConfig):
         x = ctx.lookup("vocab_embed", tokens, fields=("vec",))["vec"]
 
         hot = ctx.hot_experts("router")
+        # named scopes label the device ops of each layer part in the
+        # compiled program's metadata; they cost nothing at run time
         for lp in params["layers"]:
-            x = x + attention(lp, rmsnorm(lp["norm1"], x))
+            with jax.named_scope("attention"):
+                x = x + attention(lp, rmsnorm(lp["norm1"], x))
             h = rmsnorm(lp["norm2"], x)
             h2d = h.reshape(B * S, -1)
             # instrumented router site: record expert choices
             from ..models.moe import route
-            _, ids, _ = route(lp["moe"]["w_router"], h2d, cfg.top_k,
-                              lp["moe"].get("b_router"))
-            ctx.lookup("router", ids.reshape(-1), fields=("idx",))
+            with jax.named_scope("moe.router"):
+                _, ids, _ = route(lp["moe"]["w_router"], h2d, cfg.top_k,
+                                  lp["moe"].get("b_router"))
+                ctx.lookup("router", ids.reshape(-1), fields=("idx",))
             if hot:
-                y, _ = moe_ffn_hotpath(lp["moe"], h2d, model_cfg, hot)
+                with jax.named_scope("moe.hot"):
+                    y, _ = moe_ffn_hotpath(lp["moe"], h2d, model_cfg, hot)
             else:
-                y, _ = moe_ffn_local(lp["moe"], h2d, moe_cfg)
+                with jax.named_scope("moe.generic"):
+                    y, _ = moe_ffn_local(lp["moe"], h2d, moe_cfg)
             x = x + y.reshape(B, S, -1)
 
         # adapter branch: fully eliminated when the adapter bank is empty

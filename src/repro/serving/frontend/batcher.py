@@ -14,10 +14,17 @@ whole window.  Windows retire through a bounded in-flight deque
 (``cfg.inflight``), so the host forms window N+1 while the device runs
 window N.
 
-Fan-back slices each request's rows out of the window output and
-records queue-wait / batch-wait / execute / total into the runtime's
-:class:`~repro.core.histogram.StreamingHistogram` series — ONE locked
-stats call per retired window, same discipline as dispatch itself.
+Fan-back slices each request's rows out of the window output, sets
+each request's ``timing`` and records queue-wait and total into the
+runtime's :class:`~repro.core.histogram.StreamingHistogram` series —
+ONE locked stats call per retired window, same discipline as dispatch
+itself.
+
+Each dispatched window is numbered (``w``): its ``morpheus.batcher.pump``
+span (with ``fill``, ``pack`` and the runtime's ``place``/``step_many``
+nested inside) and its ``morpheus.batcher.retire`` span (``wait``,
+``d2h``, ``fanback``) carry the same ``w``, so a trace pairs them
+(:mod:`repro.core.tracing`).
 
 Bucket misprediction is detected here: each formed batch whose ideal
 ladder bucket is missing from the active plan's bucket set counts as a
@@ -36,6 +43,7 @@ import jax
 import numpy as np
 
 from ...core.passes.batch_shape import plan_batch_shape
+from ...core.tracing import span
 from ..dataplane import make_request_batch
 
 
@@ -53,8 +61,9 @@ class DynamicBatcher:
         self.clock = clock
         self.keep_outputs = keep_outputs
         self._ladder = cfg.ladder_resolved()
-        # (device_out, chunks, t_dispatch, bucket, mispredicts)
+        # (device_out, chunks, t_dispatch, bucket, mispredicts, w)
         self._inflight: Deque[tuple] = deque()
+        self._w = 0                   # dispatched-window ordinal
         self._mis_batches = 0
         self._mis_hits = 0
 
@@ -83,9 +92,29 @@ class DynamicBatcher:
         if not self.queue.wait_nonempty(wait_s):
             self._retire(0)
             return 0
-        buckets, k = self.current_shape()
-        primary = buckets[-1]
-        target = primary * max(k, 1)
+        # a window that takes only expired requests dispatches nothing:
+        # its pump span then has no retire span of the same w
+        self._w += 1
+        with span("batcher.pump", w=self._w):
+            buckets, k = self.current_shape()
+            primary = buckets[-1]
+            target = primary * max(k, 1)
+            with span("batcher.fill", target=target):
+                rows = self._fill(target)
+            dispatched = bool(rows) and self._dispatch(rows, buckets)
+        if not rows:
+            self._retire(0)
+            return 0
+        if dispatched:
+            # bounded pipelining: keep at most cfg.inflight windows
+            # un-retired so the host forms the next window while the
+            # device runs this one — but never unboundedly many
+            self._retire(max(self.cfg.inflight - 1, 0))
+        return len(rows)
+
+    def _fill(self, target: int) -> List:
+        """Take up to ``target`` requests, waiting at most
+        ``cfg.max_wait_s`` from now for more to arrive."""
         fill_deadline = self.clock() + self.cfg.max_wait_s
         rows: List = []
         while True:
@@ -100,11 +129,7 @@ class DynamicBatcher:
                 break
             if not self.queue.wait_nonempty(remaining):
                 break
-        if not rows:
-            self._retire(0)
-            return 0
-        self._dispatch(rows, buckets)
-        return len(rows)
+        return rows
 
     def _finish_shed(self, shed: List) -> None:
         if not shed:
@@ -136,7 +161,9 @@ class DynamicBatcher:
         self.rt.stats.bump(requests_failed=n)
 
     # ---- dispatch -----------------------------------------------------
-    def _dispatch(self, rows: List, buckets: Tuple[int, ...]) -> None:
+    def _dispatch(self, rows: List, buckets: Tuple[int, ...]) -> bool:
+        """Pack, place and dispatch one window; False when the dispatch
+        raised and the window's requests were failed instead."""
         primary = buckets[-1]
         if len(rows) <= primary:
             chunks = [rows]
@@ -160,8 +187,10 @@ class DynamicBatcher:
                     else now
         self._maybe_deopt(len(chunks), mispredicts)
 
-        raw = [make_request_batch([r.payload for r in chunk], bucket)
-               for chunk in chunks]
+        with span("batcher.pack", rows=len(rows), k=len(chunks),
+                  bucket=bucket, pad=bucket * len(chunks) - len(rows)):
+            raw = [make_request_batch([r.payload for r in chunk], bucket)
+                   for chunk in chunks]
         placed = self.rt.place_batch(raw, fused=True)
         t_disp = self.clock()
         try:
@@ -172,13 +201,10 @@ class DynamicBatcher:
             # keep serving — the next window routes through the generic
             # executable
             self._fail_window(chunks, e)
-            return
+            return False
         self._inflight.append((out, chunks, t_disp, bucket,
-                               mispredicts))
-        # bounded pipelining: keep at most cfg.inflight windows
-        # un-retired so the host forms the next window while the device
-        # runs this one — but never unboundedly many
-        self._retire(max(self.cfg.inflight - 1, 0))
+                               mispredicts, self._w))
+        return True
 
     def _maybe_deopt(self, n_batches: int, mispredicts: int) -> None:
         self._mis_batches += n_batches
@@ -206,23 +232,31 @@ class DynamicBatcher:
 
     def _retire(self, limit: int) -> None:
         while len(self._inflight) > limit:
-            out, chunks, t_disp, bucket, mispredicts = \
+            out, chunks, t_disp, bucket, mispredicts, w = \
                 self._inflight.popleft()
-            if self.keep_outputs:
-                host = jax.tree.map(np.asarray, out)  # blocks + D2H
-            else:
-                host = jax.block_until_ready(out)     # latency only
-            t_done = self.clock()
-            series = {"request_queue_wait_s": [],
-                      "request_batch_wait_s": [],
-                      "request_execute_s": [],
-                      "request_total_s": []}
+            with span("batcher.retire", w=w):
+                self._retire_one(out, chunks, t_disp, bucket,
+                                 mispredicts)
+
+    def _retire_one(self, out, chunks: List[List], t_disp: float,
+                    bucket: int, mispredicts: int) -> None:
+        with span("batcher.retire.wait"):
+            jax.block_until_ready(out)
+        host = None
+        if self.keep_outputs:
+            nbytes = sum(x.nbytes for x in jax.tree.leaves(out))
+            with span("batcher.retire.d2h", bytes=nbytes):
+                host = jax.tree.map(np.asarray, out)
+        t_done = self.clock()
+        with span("batcher.retire.fanback",
+                  requests=sum(len(c) for c in chunks)):
+            series = {"request_queue_wait_s": [], "request_total_s": []}
             completed = met = missed = pad = 0
             for j, chunk in enumerate(chunks):
                 pad += bucket - len(chunk)
                 for i, r in enumerate(chunk):
                     output = None
-                    if self.keep_outputs:
+                    if host is not None:
                         output = jax.tree.map(
                             lambda x, j=j, i=i: x[j, i], host)
                     taken = r._taken_ts if r._taken_ts is not None \
@@ -241,15 +275,11 @@ class DynamicBatcher:
                     completed += 1
                     series["request_queue_wait_s"].append(
                         timing["queue_wait_s"])
-                    series["request_batch_wait_s"].append(
-                        timing["batch_wait_s"])
-                    series["request_execute_s"].append(
-                        timing["execute_s"])
                     series["request_total_s"].append(timing["total_s"])
                     r.finish("ok", output=output, timing=timing,
                              slo_met=slo)
-            # ONE locked stats call per retired window: all four
-            # histogram series + every counter delta together
+            # ONE locked stats call per retired window: both histogram
+            # series + every counter delta together
             self.rt.stats.observe_many(
                 series, requests_completed=completed, slo_met=met,
                 slo_missed=missed, batches_formed=len(chunks),

@@ -82,11 +82,20 @@ class Request:
     the data plane consumes; ``deadline`` is absolute (same clock as the
     frontend's).  Terminal state lands in ``status`` ("ok", "rejected",
     "shed", "failed"), ``output`` (the per-request slice of the batch
-    output), ``timing`` (queue_wait_s / batch_wait_s / execute_s /
-    total_s), ``slo_met`` (None for deadline-less requests) and
+    output), ``timing``, ``slo_met`` (None for deadline-less requests) and
     ``reason`` (the machine-readable *why* of a non-"ok" terminal state
     — ``QUEUE_FULL``, ``PLANE_DEGRADED``, ``DEADLINE_EXPIRED``,
-    ``PLANE_FAULT``); :meth:`wait` blocks until then."""
+    ``PLANE_FAULT``); :meth:`wait` blocks until then.
+
+    ``timing`` of a request served "ok", in seconds on the frontend's
+    clock: ``queue_wait_s`` from submit until the batcher closed its
+    window (it includes the window's fill); ``batch_wait_s`` from there
+    until dispatch (packing and placing the window's batch);
+    ``execute_s`` from dispatch to the end of the window's device-to-host
+    copy (of its device steps where outputs are not kept) — the wait
+    behind the window still in flight, the window's own device steps and
+    the copy, but not the fan-back that follows; ``total_s`` from submit
+    to that same end."""
     id: int
     payload: Any
     arrival_ts: float
