@@ -11,7 +11,15 @@ The predicate is the injected branch; the hot-expert path is the
 specialized code; the generic path is the in-graph deopt target.  This is
 traffic-dependent and self-guarding (the predicate IS the guard — unlike a
 version guard it re-validates per batch, so router drift degrades to the
-generic path instead of computing garbage)."""
+generic path instead of computing garbage).
+
+The hot set is a trace-time tuple, so the fast branch reads each hot
+expert's weights in place, as a static slice of the expert stacks along
+their leading axis, and runs its FFN densely over every row; each top-k
+slot then selects the row of the expert it chose.  A fancy-index gather
+of the stacks (``w[hot_arr]``) is not folded away, since the weights are
+arguments: on a TPU it copies every touched column block of all E
+experts on every step."""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -21,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models.config import ModelConfig
-from ...models.moe import _expert_compute, route
+from ...models.moe import route
 from ..instrument import SketchConfig
 from ..specialize import SiteSpec
 from .registry import SpecializationPass
@@ -61,22 +69,17 @@ class MoEFastPathPass(SpecializationPass):
 
 def moe_ffn_hotpath(params, x2d: jax.Array, cfg: ModelConfig,
                     hot_experts: Tuple[int, ...], act: str = "silu"):
-    """Specialized MoE FFN: hot experts' weights are pre-sliced
-    (trace-time constant indices -> contiguous fast weights); a lax.cond
-    falls back to the full dropless dispatch on hot-set miss.
+    """Specialized MoE FFN: the hot experts' weights are read in place by
+    static slices (``params["w1"][e]`` for each trace-time hot ``e``); a
+    lax.cond falls back to the full dropless dispatch on hot-set miss.
+    The fast branch runs each hot expert densely over all rows.
 
     Returns (y, metrics) like moe_ffn_local."""
     from ...models.moe import moe_ffn_local
 
     moe = cfg.moe
-    T, D = x2d.shape
     E, K = moe.num_experts, moe.top_k
     H = len(hot_experts)
-    hot_arr = jnp.asarray(np.asarray(hot_experts, np.int32))
-    # static slice of the expert stacks (constant folded at compile time)
-    w1h = params["w1"][hot_arr]
-    w3h = params["w3"][hot_arr]
-    w2h = params["w2"][hot_arr]
 
     gates, ids, logits = route(params["w_router"], x2d, K,
                                params.get("b_router"))
@@ -90,15 +93,19 @@ def moe_ffn_hotpath(params, x2d: jax.Array, cfg: ModelConfig,
     all_hot = jnp.all(hot_ids >= 0)
 
     def fast():
-        flat = hot_ids.reshape(-1)
-        safe = jnp.maximum(flat, 0)
-        order = jnp.argsort(safe)
-        xs = x2d[order // K]
-        gs = jnp.bincount(safe, length=H).astype(jnp.int32)
-        ys = _expert_compute(xs, gs, w1h, w3h, w2h, act)
-        y = jnp.zeros_like(ys).at[order].set(ys)
-        y = (y.reshape(T, K, D) *
-             gates[..., None].astype(ys.dtype)).sum(axis=1)
+        # each hot expert over every row, its weights read where they
+        # lie; each top-k slot then selects its expert's row, and the
+        # slots combine as in moe_ffn_local
+        ys = []
+        for e in hot_experts:
+            h1 = x2d @ params["w1"][e]
+            h = (jax.nn.silu(h1) if act == "silu" else jax.nn.gelu(h1)) \
+                * (x2d @ params["w3"][e])
+            ys.append(h @ params["w2"][e])
+        y = ys[0][:, None, :]
+        for e, ye in zip(hot_experts[1:], ys[1:]):
+            y = jnp.where((ids == e)[..., None], ye[:, None, :], y)
+        y = (y * gates[..., None].astype(y.dtype)).sum(axis=1)
         return y.astype(x2d.dtype)
 
     def slow():

@@ -13,9 +13,9 @@ Two implementations share the same math:
   are dropped (counted in metrics) — GShard semantics with a configurable
   capacity factor.
 
-The Morpheus *hot-expert fast path* (core/passes/fastpath.py) reuses
-``_expert_compute`` with a pre-sliced hot subset of the expert weights and
-an in-graph guard.
+The Morpheus *hot-expert fast path* (``core/passes/branch_inject.py``)
+reads the hot experts' weights in place, as static slices of these
+stacks, and runs them densely over every row behind an in-graph guard.
 """
 from __future__ import annotations
 

@@ -120,3 +120,29 @@ def test_moe_hotpath_compiles_in_fused_window(one_chip):
             return carry, y
         return jax.lax.scan(body, 0, xs)[1]
     _compile_text(window, params, spec((2, T, D)))
+
+
+@pytest.mark.parametrize("hot", [(0, 1, 2), (3, 7, 11)])
+def test_moe_hotpath_reads_hot_weights_in_place(one_chip, hot):
+    """At phi3.5-moe widths and a serving window's 128 rows the fast
+    branch reads the hot experts' weights where they lie.  A gather of
+    the stacks by a constant index once lowered to one mini-gather per
+    128-column block of all 16 experts (``f32[16,4096,128]``), a
+    ``while`` picking the hot rows and a ``dynamic-update-slice``
+    writing a fresh ``f32[3,4096,6400]`` stack, on every step."""
+    E, D, F, T = 16, 4096, 6400, 128
+    cfg = ModelConfig(d_model=D, moe=MoEConfig(num_experts=E, top_k=2,
+                                               expert_d_ff=F))
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    params = {"w_router": spec((D, E)), "b_router": spec((E,)),
+              "w1": spec((E, D, F)), "w3": spec((E, D, F)),
+              "w2": spec((E, F, D))}
+    text = _compile_text(
+        lambda p, x: moe_ffn_hotpath(p, x, cfg, hot)[0], params,
+        spec((T, D)))
+    H = len(hot)
+    for banned in ("while", "dynamic-update-slice", f"f32[{E},{D},128]",
+                   f"f32[{H},{D},{F}]", f"f32[{H},{F},{D}]"):
+        assert banned not in text, banned
