@@ -133,12 +133,16 @@ class DataPlaneCtx:
             return plan_flags[name]       # trace-time constant -> DCE
         return default
 
-    def hot_experts(self, table: str) -> Optional[Tuple[int, ...]]:
+    def hot_experts(self, table: str, site: Optional[int] = None
+                    ) -> Optional[Tuple[int, ...]]:
         """Hot set the MoE fast-path pass planned for ``table``'s lookup
         site (branch injection, §4.3.5), or None when the pass did not
-        fire.  A trace-time constant: the caller's hot path is compiled in
-        or left out entirely."""
-        return self.fastpath_keys(table, "moe_fastpath")
+        fire.  ``site`` picks the ``site``-th lookup of ``table`` in the
+        step (one per MoE layer); None takes the first planned.  A
+        trace-time constant: the caller's hot path is compiled in or left
+        out entirely."""
+        key = table if site is None else f"{table}#{site}"
+        return self.fastpath_keys(key, "moe_fastpath")
 
     def fastpath_keys(self, table: str, impl: str = "moe_fastpath"
                       ) -> Optional[Tuple[int, ...]]:

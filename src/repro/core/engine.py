@@ -224,7 +224,9 @@ class MorpheusEngine:
             if instrument.n_shards(st) is not None:
                 st = instrument.merge_shards(st)
             hot, cov, total = instrument.hot_keys(st, self.cfg.sketch)
-            hot_stats[sid] = (hot, cov)
+            # the heaviest keys, in canonical order: the same hot set
+            # plans the same signature however the estimates rank it
+            hot_stats[sid] = (np.sort(hot), cov)
 
         inputs = PlanInputs(mutability=dict(self.mutability),
                             hot_stats=hot_stats, sketch=self.cfg.sketch,

@@ -19,7 +19,14 @@ their leading axis, and runs its FFN densely over every row; each top-k
 slot then selects the row of the expert it chose.  A fancy-index gather
 of the stacks (``w[hot_arr]``) is not folded away, since the weights are
 arguments: on a TPU it copies every touched column block of all E
-experts on every step."""
+experts on every step.
+
+A layer that holds one chip's share of the experts (``MoEConfig.held``)
+routes over all of them.  The router table then carries a ``held``
+field, the pass plans hot experts among the held ones only, and the
+guard asks that every slot routed to a *held* expert be hot: a slot
+routed to another chip is neither a hit nor a miss, and adds nothing
+on either branch."""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -29,7 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...models.config import ModelConfig
-from ...models.moe import route
 from ..instrument import SketchConfig
 from ..specialize import SiteSpec
 from .registry import SpecializationPass
@@ -61,6 +67,9 @@ class MoEFastPathPass(SpecializationPass):
 
     def plan(self, site, snapshot, stats):
         hot, coverage = stats.hot_for(site.site_id)
+        table = snapshot.get(site.table)
+        if table is not None and "held" in table.fields and len(hot):
+            hot = hot[np.asarray(table.fields["held"])[hot] > 0]
         keys = plan_moe_fastpath(hot, coverage, stats.sketch)
         if keys is None:
             return None
@@ -70,19 +79,20 @@ class MoEFastPathPass(SpecializationPass):
 def moe_ffn_hotpath(params, x2d: jax.Array, cfg: ModelConfig,
                     hot_experts: Tuple[int, ...], act: str = "silu"):
     """Specialized MoE FFN: the hot experts' weights are read in place by
-    static slices (``params["w1"][e]`` for each trace-time hot ``e``); a
-    lax.cond falls back to the full dropless dispatch on hot-set miss.
-    The fast branch runs each hot expert densely over all rows.
+    static slices (``params["w1"][i]`` for each trace-time hot expert at
+    stack index ``i``); a lax.cond falls back to the full dropless
+    dispatch (over the held experts) on a hot-set miss.  The fast branch
+    runs each hot expert densely over all rows.
 
-    Returns (y, metrics) like moe_ffn_local."""
-    from ...models.moe import moe_ffn_local
+    Returns (y, metrics) like moe_ffn_local, plus ``fastpath_hit``."""
+    from ...models.moe import held_remap, moe_ffn_local, route_for
 
     moe = cfg.moe
-    E, K = moe.num_experts, moe.top_k
+    E = moe.num_experts
     H = len(hot_experts)
+    stack = held_remap(moe)
 
-    gates, ids, logits = route(params["w_router"], x2d, K,
-                               params.get("b_router"))
+    gates, ids, logits = route_for(params, x2d, moe)
     # remap: global expert id -> hot slot (or -1), a trace-time constant.
     # Built on the host: as an in-graph scatter inside a lax.scan body
     # (fused windows) the TPU compiler aborts on it when the hot set is
@@ -90,6 +100,9 @@ def moe_ffn_hotpath(params, x2d: jax.Array, cfg: ModelConfig,
     remap_np = np.full((E,), -1, np.int32)
     remap_np[list(hot_experts)] = np.arange(H, dtype=np.int32)
     hot_ids = jnp.asarray(remap_np)[ids]              # (T,K)
+    if moe.held:
+        # slots routed to another chip's experts neither hit nor miss
+        hot_ids = jnp.where(jnp.asarray(stack)[ids] >= 0, hot_ids, 0)
     all_hot = jnp.all(hot_ids >= 0)
 
     def fast():
@@ -98,12 +111,19 @@ def moe_ffn_hotpath(params, x2d: jax.Array, cfg: ModelConfig,
         # slots combine as in moe_ffn_local
         ys = []
         for e in hot_experts:
-            h1 = x2d @ params["w1"][e]
+            i = int(stack[e])
+            h1 = x2d @ params["w1"][i]
             h = (jax.nn.silu(h1) if act == "silu" else jax.nn.gelu(h1)) \
-                * (x2d @ params["w3"][e])
-            ys.append(h @ params["w2"][e])
-        y = ys[0][:, None, :]
-        for e, ye in zip(hot_experts[1:], ys[1:]):
+                * (x2d @ params["w3"][i])
+            ys.append(h @ params["w2"][i])
+        if moe.held:
+            first = jnp.zeros_like(ys[0])[:, None, :]
+            rest = zip(hot_experts, ys)
+        else:
+            first = ys[0][:, None, :]
+            rest = zip(hot_experts[1:], ys[1:])
+        y = first
+        for e, ye in rest:
             y = jnp.where((ids == e)[..., None], ye[:, None, :], y)
         y = (y * gates[..., None].astype(y.dtype)).sum(axis=1)
         return y.astype(x2d.dtype)

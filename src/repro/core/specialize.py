@@ -56,13 +56,14 @@ class SpecializationPlan:
                       ) -> Optional[Tuple[int, ...]]:
         """Hot set a branch-injection pass (``moe_fastpath``,
         ``ssd_fastpath``, ...) planned for one of ``table``'s lookup
-        sites (any table when None), or None when no such site was
+        sites (any table when None; one site when ``table`` is a site id
+        such as ``router#2``), or None when no such site was
         specialized.  A trace-time constant — the caller compiles its
         injected branch in or leaves it out entirely."""
         for sid, spec in self.sites:
             if spec.impl != impl:
                 continue
-            if table is None or sid.split("#")[0] == table:
+            if table is None or sid == table or sid.split("#")[0] == table:
                 return spec.hot_keys or None
         return None
 

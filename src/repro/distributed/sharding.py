@@ -44,6 +44,7 @@ def make_rules(multi_pod: bool, *, fsdp: bool = True,
         # collective on deepseek-v2 train).  FSDP-shard it instead; heads
         # carry the TP.
         "kv_lora": fsdp_axes,
+        "q_lora": fsdp_axes,          # likewise contracted by w_uq
         "ssm_heads": m,
         "ssm_in": m,
         "embed": fsdp_axes,           # FSDP / ZeRO shard dim

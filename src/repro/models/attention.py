@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from ..distributed.compat import shard_map
 from .config import MLAConfig, ModelConfig
-from .layers import apply_rope, dot_f32
+from .layers import apply_rope, dot_f32, rmsnorm, yarn_mscale
 from .params import Initializer
 
 NEG_INF = -1e30
@@ -44,12 +44,27 @@ def init_attention(ini: Initializer, cfg: ModelConfig):
 
 
 def init_mla_attention(ini: Initializer, cfg: ModelConfig):
+    """DeepSeek-V2's MLA: queries through a low-rank ``w_dq`` ->
+    RMSNorm -> ``w_uq`` (full-rank ``wq`` when ``q_lora_rank`` is 0); the
+    compressed ``c_kv`` (``w_dkv``) is RMS-normed before ``w_uk``/``w_uv``
+    up-project it; one rope key (``w_krope``) is shared by every head."""
     m: MLAConfig = cfg.mla
     d, h = cfg.d_model, cfg.n_heads
     qk = m.qk_nope_dim + m.qk_rope_dim
+    if m.q_lora_rank:
+        q = {"w_dq": ini.normal((d, m.q_lora_rank), ("embed", "q_lora")),
+             "q_norm": {"scale": ini.ones((m.q_lora_rank,), ("q_lora",),
+                                          dtype=jnp.float32)},
+             "w_uq": ini.normal((m.q_lora_rank, h, qk),
+                                ("q_lora", "q_heads", "head_dim"),
+                                fan_in=m.q_lora_rank)}
+    else:
+        q = {"wq": ini.normal((d, h, qk), ("embed", "q_heads", "head_dim"))}
     return {
-        "wq": ini.normal((d, h, qk), ("embed", "q_heads", "head_dim")),
+        **q,
         "w_dkv": ini.normal((d, m.kv_lora_rank), ("embed", "kv_lora")),
+        "kv_norm": {"scale": ini.ones((m.kv_lora_rank,), ("kv_lora",),
+                                      dtype=jnp.float32)},
         "w_krope": ini.normal((d, m.qk_rope_dim), ("embed", "head_dim")),
         "w_uk": ini.normal((m.kv_lora_rank, h, m.qk_nope_dim),
                            ("kv_lora", "q_heads", "head_dim"),
@@ -313,17 +328,30 @@ def mla_forward(params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     the absorbed form contracts scores/PV at rank r=512 instead of
     192/128, measured ~4 s/chip extra on deepseek-v2 train_4k; the naive
     per-block up-projection costs less than it saves at S>=block.
+
+    Queries go through the q-LoRA and its RMSNorm when ``q_lora_rank`` is
+    set; ``c_kv`` is RMS-normed; with ``cfg.rope_scaling`` the rope is
+    YaRN's and the scores are scaled by ``mscale_all_dim``'s m^2.  The key
+    block is capped at the key length.
     """
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
+    yarn = cfg.rope_scaling
+    if m.q_lora_rank:
+        cq = jnp.einsum("bsd,dr->bsr", x, params["w_dq"])
+        cq = rmsnorm(params["q_norm"], cq, cfg.rms_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, params["w_uq"])
+    else:
+        q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])
     q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, yarn)
 
     ckv = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])
+    # the cache holds c_kv normed: the norm is per token
+    ckv = rmsnorm(params["kv_norm"], ckv, cfg.rms_eps)
     k_rope = jnp.einsum("bsd,dr->bsr", x, params["w_krope"])[:, :, None, :]
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)[:, :, 0, :]
+    k_rope = apply_rope(k_rope, positions, cfg.rope_theta, yarn)[:, :, 0, :]
 
     new_cache = None
     if cache is not None:
@@ -341,9 +369,13 @@ def mla_forward(params, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
         kv_pos = positions
 
     scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    if yarn is not None and yarn.mscale_all_dim:
+        scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
     absorb = S == 1
 
     Sk = ckv.shape[1]
+    # a block longer than the keys would only up-project padding
+    block = min(block, Sk)
     nb = -(-Sk // block)
     pad = nb * block - Sk
     if pad:

@@ -25,13 +25,30 @@ class LayerSpec:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 8
+    num_experts: int = 8          # the router's width
     top_k: int = 2
     expert_d_ff: int = 0          # 0 -> use model d_ff
     num_shared: int = 0           # shared (always-on) experts
     shared_d_ff: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # Routing.  "topk_softmax" (Mixtral, phi3.5-moe): the top-k logits,
+    # softmaxed over the k.  "softmax" (DeepSeek-V2): softmax over every
+    # expert; with n_group > 1 only the topk_group groups of the largest
+    # score are eligible; each gate is routed_scaling x its score, not
+    # renormalised.
+    scoring: str = "topk_softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+    # Routed experts held by this layer (one chip's share of an
+    # expert-parallel deployment); empty -> all of them.  The expert
+    # stacks hold these, in this order.
+    held: Tuple[int, ...] = ()
+
+    @property
+    def held_ids(self) -> Tuple[int, ...]:
+        return self.held or tuple(range(self.num_experts))
 
 
 @dataclass(frozen=True)
@@ -41,6 +58,20 @@ class MLAConfig:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     q_lora_rank: int = 0          # 0 -> full-rank q projection
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as DeepSeek-V2 configures it:
+    rope frequencies blended between interpolated (/ ``factor``) and
+    original across the correction range that ``beta_fast`` and
+    ``beta_slow`` set, and attention scores scaled by mscale squared."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -65,6 +96,7 @@ class ModelConfig:
     d_ff: int = 1024
     vocab: int = 1024
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YaRNConfig] = None   # MLA's rope only
     rms_eps: float = 1e-6
     tie_embeddings: bool = False
 
@@ -141,11 +173,14 @@ class ModelConfig:
                 top_k=min(moe.top_k, 2),
                 expert_d_ff=min(moe.expert_d_ff or 128, 128),
                 num_shared=min(moe.num_shared, 1),
-                shared_d_ff=min(moe.shared_d_ff or 128, 128))
+                shared_d_ff=min(moe.shared_d_ff or 128, 128),
+                n_group=min(moe.n_group, 2),
+                topk_group=min(moe.topk_group, 1), held=())
         mla = self.mla
         if mla is not None:
             mla = MLAConfig(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
-                            v_head_dim=16)
+                            v_head_dim=16,
+                            q_lora_rank=32 if mla.q_lora_rank else 0)
         ssm = self.ssm
         if ssm is not None:
             ssm = dataclasses.replace(ssm, d_state=16, head_dim=8, chunk=16)

@@ -1,6 +1,7 @@
 """Common layers: RMSNorm, embeddings, RoPE, gated FFN, logit head."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -67,17 +68,53 @@ def unembed(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_freqs(dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def rope_freqs(dim: int, theta: float, yarn=None) -> jax.Array:
+    """Rope frequencies of ``dim`` / 2 pairs; with ``yarn`` (a
+    :class:`~repro.models.config.YaRNConfig`) YaRN's blend: frequencies
+    below the correction range are divided by ``factor``, those above it
+    kept, and a linear ramp between (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    base = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / (theta ** base)
+    if yarn is None:
+        return extra
+    inter = extra / yarn.factor
+
+    def corr_dim(rotations):
+        return dim * math.log(yarn.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                     # 1: extrapolate, 0: interpolate
+    return inter * (1.0 - keep) + extra * keep
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  Rotates
+    the pairs (i, i + head_dim/2).  With ``yarn``, YaRN's frequencies,
+    and cos/sin scaled by ``mscale(mscale) / mscale(mscale_all_dim)``."""
     dim = x.shape[-1]
-    freqs = rope_freqs(dim, theta)                      # (dim/2,)
+    freqs = rope_freqs(dim, theta, yarn)                # (dim/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, d/2)
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
+    if yarn is not None:
+        m = yarn_mscale(yarn.factor, yarn.mscale) \
+            / yarn_mscale(yarn.factor, yarn.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

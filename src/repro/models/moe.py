@@ -23,6 +23,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..distributed.compat import shard_map
 from ..distributed.meshctx import get_policy
@@ -36,18 +37,19 @@ from .params import Initializer
 # ---------------------------------------------------------------------------
 
 def init_moe(ini: Initializer, cfg: ModelConfig):
+    """The router spans every expert; the stacks hold ``moe.held_ids``."""
     moe: MoEConfig = cfg.moe
     d = cfg.d_model
     f = moe.expert_d_ff or cfg.d_ff
+    n = len(moe.held_ids)
     p = {
         "w_router": ini.normal((d, moe.num_experts), ("embed", None),
                                dtype=jnp.float32),
         "b_router": ini.zeros((moe.num_experts,), (None,),
                               dtype=jnp.float32),
-        "w1": ini.normal((moe.num_experts, d, f), ("experts", "embed", "mlp")),
-        "w3": ini.normal((moe.num_experts, d, f), ("experts", "embed", "mlp")),
-        "w2": ini.normal((moe.num_experts, f, d), ("experts", "mlp", "embed"),
-                         fan_in=f),
+        "w1": ini.normal((n, d, f), ("experts", "embed", "mlp")),
+        "w3": ini.normal((n, d, f), ("experts", "embed", "mlp")),
+        "w2": ini.normal((n, f, d), ("experts", "mlp", "embed"), fan_in=f),
     }
     if moe.num_shared:
         p["shared"] = init_ffn(ini, d, moe.num_shared *
@@ -69,6 +71,53 @@ def route(w_router, x2d: jax.Array, top_k: int, bias=None):
     gates, ids = jax.lax.top_k(logits, top_k)
     gates = jax.nn.softmax(gates, axis=-1)
     return gates, ids.astype(jnp.int32), logits
+
+
+def route_grouped(w_router, x2d: jax.Array, moe: MoEConfig, bias=None):
+    """DeepSeek-V2 routing: scores are the softmax of the logits over
+    every expert; a group's score is its largest; only the
+    ``topk_group`` best of ``n_group`` groups stay eligible (the others'
+    scores zeroed); the top-k of what is left are the experts, each gated
+    by ``routed_scaling`` x its score, not renormalised.  Returns gates
+    (T,K) fp32, ids (T,K) int32, logits (T,E) fp32."""
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32),
+                        w_router.astype(jnp.float32))
+    if bias is not None:
+        logits = logits + bias.astype(jnp.float32)
+    scores = jax.nn.softmax(logits, axis=-1)
+    if moe.n_group > 1:
+        T, E = scores.shape
+        groups = scores.reshape(T, moe.n_group, E // moe.n_group)
+        _, best = jax.lax.top_k(groups.max(axis=-1), moe.topk_group)
+        keep = jax.nn.one_hot(best, moe.n_group, dtype=jnp.bool_).any(1)
+        scores = jnp.where(keep[:, :, None], groups, 0.0).reshape(T, E)
+    top, ids = jax.lax.top_k(scores, moe.top_k)
+    return top * moe.routed_scaling, ids.astype(jnp.int32), logits
+
+
+def route_for(params, x2d: jax.Array, moe: MoEConfig):
+    """The routing ``moe.scoring`` names, over the router's full width."""
+    if moe.scoring == "softmax":
+        return route_grouped(params["w_router"], x2d, moe,
+                             params.get("b_router"))
+    return route(params["w_router"], x2d, moe.top_k,
+                 params.get("b_router"))
+
+
+def held_remap(moe: MoEConfig) -> np.ndarray:
+    """Global expert id -> index in the held stacks, -1 where another
+    chip holds it (a host constant)."""
+    remap = np.full((moe.num_experts,), -1, np.int32)
+    remap[list(moe.held_ids)] = np.arange(len(moe.held_ids), dtype=np.int32)
+    return remap
+
+
+def held_slots(ids: jax.Array, moe: MoEConfig) -> jax.Array:
+    """How many of the routed slots ``ids`` land on experts this layer
+    holds (int32)."""
+    if not moe.held:
+        return jnp.int32(ids.size)
+    return (jnp.asarray(held_remap(moe))[ids] >= 0).sum(dtype=jnp.int32)
 
 
 def load_balance_loss(logits: jax.Array, ids: jax.Array, n_experts: int):
@@ -123,10 +172,14 @@ def _expert_compute_blocked(xs: jax.Array, group_sizes: jax.Array, w1, w3,
 # ---------------------------------------------------------------------------
 
 def moe_ffn_local(params, x2d: jax.Array, moe: MoEConfig, act: str = "silu"):
+    """Dropless MoE over the experts this layer holds.  With
+    ``moe.held`` set, slots routed to experts held elsewhere add nothing
+    here: they sort after the held groups and their rows are zeroed."""
     T, D = x2d.shape
     E, K = moe.num_experts, moe.top_k
-    gates, ids, logits = route(params["w_router"], x2d, K,
-                               params.get("b_router"))
+    gates, ids, logits = route_for(params, x2d, moe)
+    if moe.held:
+        return _moe_ffn_held(params, x2d, moe, act, gates, ids, logits)
 
     flat_ids = ids.reshape(-1)                                # (T*K,)
     sort_idx = jnp.argsort(flat_ids)
@@ -140,6 +193,27 @@ def moe_ffn_local(params, x2d: jax.Array, moe: MoEConfig, act: str = "silu"):
     return y.astype(x2d.dtype), {"aux_loss": aux,
                                  "dropped": jnp.zeros((), jnp.float32),
                                  "expert_counts": group_sizes}
+
+
+def _moe_ffn_held(params, x2d, moe: MoEConfig, act, gates, ids, logits):
+    T, D = x2d.shape
+    K, n = moe.top_k, len(moe.held_ids)
+    local = jnp.asarray(held_remap(moe))[ids]                 # (T,K)
+    flat = jnp.where(local >= 0, local, n).reshape(-1)        # elsewhere last
+    sort_idx = jnp.argsort(flat)
+    xs = x2d[sort_idx // K]
+    group_sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
+    ys = _expert_compute(xs, group_sizes, params["w1"], params["w3"],
+                         params["w2"], act)
+    here = jnp.arange(T * K) < group_sizes.sum()
+    ys = jnp.where(here[:, None], ys, 0)
+    y = jnp.zeros_like(ys).at[sort_idx].set(ys)               # unsort
+    y = (y.reshape(T, K, D) * gates[..., None].astype(y.dtype)).sum(axis=1)
+    aux = load_balance_loss(logits, ids, moe.num_experts)
+    return y.astype(x2d.dtype), {
+        "aux_loss": aux, "dropped": jnp.zeros((), jnp.float32),
+        "expert_counts": jnp.bincount(ids.reshape(-1), length=moe.num_experts
+                                      ).astype(jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +232,8 @@ def _moe_shard_body(x2d, w_router, b_router, w1, w3, w2, *,
     # round capacity to a lane-friendly multiple
     cap = -(-cap // 8) * 8
 
-    gates, ids, logits = route(w_router, x2d, K, b_router)
+    gates, ids, logits = route_for({"w_router": w_router,
+                                    "b_router": b_router}, x2d, moe)
     flat_ids = ids.reshape(-1)                                # (N,) N=T_l*K
     N = flat_ids.shape[0]
     dest = flat_ids // E_l                                    # owning shard
@@ -239,7 +314,8 @@ def _moe_shard_body_psum(x2d, w_router, b_router, w1, w3, w2, *,
     T, D = x2d.shape
     E, K = moe.num_experts, moe.top_k
     E_l = E // n_model
-    gates, ids, logits = route(w_router, x2d, K, b_router)
+    gates, ids, logits = route_for({"w_router": w_router,
+                                    "b_router": b_router}, x2d, moe)
     flat_ids = ids.reshape(-1)
     me = jax.lax.axis_index(model_axis)
     owned = (flat_ids // E_l) == me
@@ -338,7 +414,7 @@ def moe_ffn(params, x: jax.Array, cfg: ModelConfig):
     from ..distributed.meshctx import get_moe_hot
     hot = get_moe_hot()
     if pol is not None and pol.mesh is not None and pol.moe_impl != "local" \
-            and moe.num_experts % pol.n_model == 0:
+            and not moe.held and moe.num_experts % pol.n_model == 0:
         y, metrics = moe_ffn_sharded(params, x2d, moe, cfg.ffn_act)
         y = constrain(y, ("tokens", None))
     elif hot and len(hot) < moe.num_experts:

@@ -1,6 +1,7 @@
 from .dataplane import ServeConfig, build_fleet, build_params, \
     build_tables, make_request_batch, make_request_rows, \
     make_request_windows, make_serve_step, make_synthetic_batch
+from .lm import LMServeConfig, build_lm_tables, make_lm_serve_step
 from .frontend import ArrivalProfile, DynamicBatcher, FrontendConfig, \
     OpenLoopDriver, Request, RequestQueue, ServingFrontend, \
     bursty_onoff_gaps, poisson_gaps

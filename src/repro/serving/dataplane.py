@@ -13,6 +13,10 @@ full table cast of the paper mapped into the ML domain:
                                    experts get the dense fast path)
   sessions     (RW)  conn_table:   per-slot session state, written by the
                                    data plane itself => site guard
+  moe_stats    (RW)  —             per MoE layer, device-side counters of
+                                   the fast path: steps on the fast
+                                   branch, steps with held slots, held
+                                   and all routed slots
 
 Feature flags (control plane): ``vision_enabled`` (the QUIC-branch
 analogue) and ``track_sessions``.
@@ -132,8 +136,39 @@ def build_tables(cfg: ServeConfig, key, *, uniform_temperature=True,
                "last_token": np.zeros(cfg.n_slots, np.int32)},
               n_valid=cfg.n_slots, mutability="rw",
               instrument=instrument_sessions),
+        moe_stats_table(cfg.n_layers),
     ]
     return TableSet(tables)
+
+
+MOE_STATS = ("fast_steps", "held_steps", "held_slots", "slots")
+
+
+def moe_stats_table(n_moe_layers: int) -> Table:
+    """The ``moe_stats`` RW table: one row per MoE layer, the counters
+    :func:`record_moe_stats` adds to every step."""
+    return Table("moe_stats",
+                 {f: np.zeros(n_moe_layers, np.int32) for f in MOE_STATS},
+                 n_valid=n_moe_layers, mutability="rw", instrument=False,
+                 max_inline=0)
+
+
+def record_moe_stats(ctx, layers) -> None:
+    """Add one step to the ``moe_stats`` counters: ``layers`` holds, per
+    MoE layer, whether the step took the fast branch (0/1), how many of
+    its routed slots landed on held experts, and how many slots it
+    routed.  A fast-branch step counts only where it had held slots."""
+    idx = jnp.arange(len(layers), dtype=jnp.int32)
+    hit = jnp.stack([jnp.asarray(h, jnp.int32) for h, _, _ in layers])
+    held = jnp.stack([jnp.asarray(n, jnp.int32) for _, n, _ in layers])
+    slots = jnp.asarray([n for _, _, n in layers], jnp.int32)
+    old = ctx.lookup("moe_stats", idx, fields=MOE_STATS)
+    some = (held > 0).astype(jnp.int32)
+    ctx.update("moe_stats", idx, {
+        "fast_steps": old["fast_steps"] + hit * some,
+        "held_steps": old["held_steps"] + some,
+        "held_slots": old["held_slots"] + held,
+        "slots": old["slots"] + slots})
 
 
 def make_serve_step(cfg: ServeConfig):
@@ -169,6 +204,7 @@ def make_serve_step(cfg: ServeConfig):
         x = ctx.lookup("vocab_embed", tokens, fields=("vec",))["vec"]
 
         hot = ctx.hot_experts("router")
+        moe_stats = []
         # named scopes label the device ops of each layer part in the
         # compiled program's metadata; they cost nothing at run time
         for lp in params["layers"]:
@@ -184,10 +220,13 @@ def make_serve_step(cfg: ServeConfig):
                 ctx.lookup("router", ids.reshape(-1), fields=("idx",))
             if hot:
                 with jax.named_scope("moe.hot"):
-                    y, _ = moe_ffn_hotpath(lp["moe"], h2d, model_cfg, hot)
+                    y, m = moe_ffn_hotpath(lp["moe"], h2d, model_cfg, hot)
+                hit = m["fastpath_hit"]
             else:
                 with jax.named_scope("moe.generic"):
                     y, _ = moe_ffn_local(lp["moe"], h2d, moe_cfg)
+                hit = 0
+            moe_stats.append((hit, ids.size, ids.size))
             x = x + y.reshape(B, S, -1)
 
         # adapter branch: fully eliminated when the adapter bank is empty
@@ -215,6 +254,7 @@ def make_serve_step(cfg: ServeConfig):
             old = ctx.lookup("sessions", batch["slot"], fields=("count",))
             ctx.update("sessions", batch["slot"],
                        {"count": old["count"] + 1, "last_token": next_tok})
+        record_moe_stats(ctx, moe_stats)
         return logits
 
     return serve_step

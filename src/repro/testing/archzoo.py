@@ -51,7 +51,7 @@ from ..core import EngineConfig, SketchConfig, Table, TableSet
 from ..core.passes.branch_inject import moe_ffn_hotpath
 from ..core.passes.ssd_fastpath import ssd_init_state_hotpath
 from ..models.config import LayerSpec, ModelConfig
-from ..models.moe import moe_ffn_local, route
+from ..models.moe import moe_ffn_local, route_for
 from ..models.params import Initializer, unzip
 from ..models.ssd import _dims, init_mamba, mamba_forward_with_state
 
@@ -293,8 +293,7 @@ def _moe_block(lp, ctx, cfg: ModelConfig, h2d):
     m = cfg.moe
     # instrumented router site: record expert choices (the vip_map #2
     # sketch the hot-expert pass plans from)
-    _, ids, _ = route(lp["moe"]["w_router"], h2d, m.top_k,
-                      lp["moe"].get("b_router"))
+    _, ids, _ = route_for(lp["moe"], h2d, m)
     ctx.lookup("router", ids.reshape(-1), fields=("idx",))
     hot = ctx.hot_experts("router")
     if hot:
