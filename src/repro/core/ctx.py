@@ -51,7 +51,8 @@ class DataPlaneCtx:
 
     def __init__(self, plan, state: PlaneState,
                  sketch_cfg: instrument.SketchConfig,
-                 mesh=None, instr_axes: Tuple[str, ...] = ("data",)):
+                 mesh=None, instr_axes: Tuple[str, ...] = ("data",),
+                 rows_valid: Optional[jax.Array] = None):
         self.plan = plan
         self.tables = dict(state.tables)
         self.instr = dict(state.instr)
@@ -59,11 +60,24 @@ class DataPlaneCtx:
         self.sketch_cfg = sketch_cfg
         self.mesh = mesh
         self.instr_axes = instr_axes
+        self.rows_valid = rows_valid
 
     # ---- instrumentation ----------------------------------------------------
     def _record(self, site_id: str, idx: jax.Array) -> None:
         """Fold this lookup's keys into the site's sketch — per device
-        (``shard_map``) when the sketch is sharded, else globally."""
+        (``shard_map``) when the sketch is sharded, else globally.
+
+        With ``rows_valid`` (the batch's ``(B,)`` row mask), the keys of
+        a lookup indexed per batch row are dropped for pad rows: the
+        sketch counts the requests served, not the copies of row 0 that
+        pad a bucket.  Counting those copies flooded the candidate ring
+        whenever a window was mostly padding, and the hot set then
+        depended on how full the last sampled window happened to be."""
+        valid = self.rows_valid
+        if (valid is not None and idx.ndim >= 1
+                and idx.shape[0] == valid.shape[0]):
+            idx = jnp.where(
+                valid.reshape(valid.shape + (1,) * (idx.ndim - 1)), idx, -1)
         st = self.instr[site_id]
         if self.mesh is not None and instrument.n_shards(st) is not None:
             self.instr[site_id] = instrument.record_sharded(

@@ -30,6 +30,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from . import instrument
@@ -41,6 +42,16 @@ from .state import PlaneState
 from .tables import TableSet, analysis_sites, analyzing, \
     reset_site_counters
 from .tracing import span
+
+
+def rows_valid(batch) -> Optional[jax.Array]:
+    """The batch's row mask, where it has one: the boolean ``(B,)``
+    ``"valid"`` leaf that the serving frontend's ``make_request_batch``
+    adds to mark real rows among a bucket's pad rows."""
+    valid = batch.get("valid") if isinstance(batch, dict) else None
+    if valid is None or valid.ndim != 1 or valid.dtype != jnp.bool_:
+        return None
+    return valid
 
 
 @dataclass
@@ -265,7 +276,8 @@ class MorpheusEngine:
             reset_site_counters()
             ctx = DataPlaneCtx(plan, state, self.cfg.sketch,
                                mesh=self.cfg.mesh,
-                               instr_axes=self.cfg.instr_axes)
+                               instr_axes=self.cfg.instr_axes,
+                               rows_valid=rows_valid(batch))
             out = self.user_step(params, ctx, batch)
             return out, ctx.outputs()
         return step

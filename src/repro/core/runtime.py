@@ -101,14 +101,20 @@ import numpy as np
 _device_put = jax.device_put
 
 
+@jax.jit
+def _stack_window(batches):
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
+
+
 def stack_batches(batches: Sequence[Any]):
     """Stack K same-shaped batches into one pytree with a leading window
     axis — the input contract of :meth:`MorpheusRuntime.step_many`'s
-    fused executable.  Use :meth:`MorpheusRuntime.place_batch` with
-    ``fused=True`` to also device-place the stack ahead of dispatch."""
-    if len(batches) == 1:
-        return jax.tree.map(lambda x: jnp.asarray(x)[None], batches[0])
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
+    fused executable, K=1 included.  One jitted call stacks the whole
+    window, whatever its K and leaf count (jit's cache keys it by
+    structure): an eager op per leaf costs a host dispatch each.  Use
+    :meth:`MorpheusRuntime.place_batch` with ``fused=True`` to also
+    device-place the stack ahead of dispatch."""
+    return _stack_window(tuple(batches))
 
 
 def _induced_window_avals(plan, fused_shapes):
@@ -467,11 +473,6 @@ class MorpheusRuntime:
                             Callable] = (
             self.generic_plan, gen_exec, gen_instr, gen_exec)
         self._example_batch = example_batch
-        # the single-step executables above are AOT-compiled against the
-        # example batch's exact structure; step_many consults this key
-        # to decide whether a K=1 window may take the step() fast path
-        # or must go through the per-structure fused machinery
-        self._example_bkey = batch_key(example_batch)
         # optional traffic-profile source (the serving frontend's
         # ArrivalProfile): snapshotted at each recompile cycle and
         # merged into the plan inputs — see attach_profile
@@ -895,11 +896,12 @@ class MorpheusRuntime:
 
         This is the steady-state fast path: one Python dispatch, one
         claim/commit pair and one locked stats update amortize over K
-        steps.  The program guard and the sampling decision are hoisted
-        to window granularity — the whole window runs specialized,
-        instrumented, or (guard tripped) generic; a control update
-        landing mid-window is queued and drained at the window's commit,
-        so the *next* window deopts (§4.4 semantics at window
+        steps.  Every K, K=1 included, runs the fused executable of the
+        window's structure.  The program guard and the sampling decision
+        are hoisted to window granularity — the whole window runs
+        specialized, instrumented, or (guard tripped) generic; a control
+        update landing mid-window is queued and drained at the window's
+        commit, so the *next* window deopts (§4.4 semantics at window
         granularity, byte-identical outputs to K=1 stepping)."""
         n = len(batches) if isinstance(batches, (list, tuple)) else k
         with span("runtime.step_many", k=n or 0):
@@ -927,17 +929,6 @@ class MorpheusRuntime:
                 raise ValueError(
                     f"step_many: leading axes {sorted(lead)} do not "
                     f"match the window size k={k}")
-        if k == 1:
-            # no fusion to amortize: run the single-step path and
-            # restack so the output contract stays (K, ...).  Only valid
-            # when the batch has the example structure the single-step
-            # executables were AOT-compiled against — a frontend pad
-            # bucket (different leading dim) must fall through to the
-            # fused machinery, which compiles and caches per structure.
-            single = jax.tree.map(lambda x: x[0], stacked)
-            if batch_key(single) == self._example_bkey:
-                out = self.step(single)
-                return jax.tree.map(lambda x: jnp.asarray(x)[None], out)
         cnt: dict = {}
         stacked = self._place_batch(stacked, stacked=True, count=cnt)
         with self._cond:

@@ -23,6 +23,8 @@ import pytest
 from repro.core import EngineConfig, MorpheusRuntime, PlaneSampling, \
     SketchConfig, Table, TableSet, stack_batches
 from repro.core import runtime as runtime_mod
+from repro.serving import ServeConfig, make_request_batch, \
+    make_request_rows
 
 N_VALID = 48
 
@@ -150,14 +152,59 @@ def test_step_many_rejects_ambiguous_prestacked_input():
         rt.close()
 
 
-def test_step_many_k1_degrades_to_single_step():
+def test_step_many_k1_degrades_to_single_step(monkeypatch):
+    """A K=1 window gives a single step's output, bit for bit, from the
+    fused K=1 executable: ``step()`` is never called, even for a window
+    with the example batch's structure."""
+    ref_rt = _mk()
+    try:
+        ref = np.asarray(ref_rt.step(_batch(3)))
+    finally:
+        ref_rt.close()
     rt = _mk()
     try:
-        out = rt.step_many([_batch(3)])
-        ref = _mk().step(_batch(3))
-        np.testing.assert_array_equal(np.asarray(out)[0], np.asarray(ref))
+        def no_step(self, batch):
+            raise AssertionError("step_many routed a K=1 window to step()")
+        monkeypatch.setattr(MorpheusRuntime, "step", no_step)
+        out = np.asarray(rt.step_many([_batch(3)]))
+        assert out.shape == (1,) + ref.shape
+        np.testing.assert_array_equal(out[0], ref)
+        assert rt.stats.steps == 1
     finally:
         rt.close()
+
+
+TINY_SERVE = ServeConfig(d_model=32, n_layers=1, n_heads=4, vocab=128,
+                         n_experts=4, d_ff=32, n_classes=8, n_slots=32,
+                         seq=4)
+
+
+def _frontend_batch(seed):
+    rows = make_request_rows(TINY_SERVE, jax.random.PRNGKey(seed), 3)
+    return make_request_batch(rows, 4)     # carries the bool ``valid``
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+@pytest.mark.parametrize("make", [_frontend_batch, _batch],
+                         ids=["frontend", "example"])
+def test_stack_batches_matches_np_stack(make, k):
+    """One jitted call stacks a window exactly as ``np.stack`` does —
+    shapes, dtypes (the frontend's bool ``valid`` leaf included) and
+    values — and a second window of the same structure compiles
+    nothing."""
+    batches = [make(10 * k + i) for i in range(k)]
+    out = stack_batches(batches)
+    assert out.keys() == batches[0].keys()
+    for name, got in out.items():
+        want = np.stack([np.asarray(b[name]) for b in batches])
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        np.testing.assert_array_equal(np.asarray(got), want)
+    if make is _frontend_batch:
+        assert out["valid"].dtype == np.bool_
+    compiled = runtime_mod._stack_window._cache_size()
+    again = stack_batches([make(100 * k + i) for i in range(k)])
+    assert runtime_mod._stack_window._cache_size() == compiled
+    assert jax.tree.map(np.shape, again) == jax.tree.map(np.shape, out)
 
 
 # ---------------------------------------------------------------------------

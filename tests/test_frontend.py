@@ -472,6 +472,48 @@ def test_step_many_serves_bucket_shapes_at_any_k():
         rt.close()
 
 
+def test_step_many_serves_primary_bucket_k1_without_step(monkeypatch):
+    """A frontend K=1 window at the primary bucket (the example batch's
+    leading dim, plus the ``valid`` leaf) is served by the fused K=1
+    executable through ``step_many``: ``step()`` is never called, and
+    the outputs equal ``run_generic``'s bit for bit."""
+    rt = _mk_rt()
+    try:
+        rows = make_request_rows(TINY, jax.random.PRNGKey(7), 5)
+        b = make_request_batch(rows, 8)          # the primary bucket
+        assert b["valid"].dtype == np.bool_
+        ref = np.asarray(rt.run_generic(b))
+
+        def no_step(self, batch):
+            raise AssertionError("step_many routed a K=1 window to step()")
+        monkeypatch.setattr(MorpheusRuntime, "step", no_step)
+        placed = rt.place_batch([b], fused=True)
+        out = np.asarray(rt.step_many(placed, k=1))
+        assert out.shape == (1,) + ref.shape
+        np.testing.assert_array_equal(out[0], ref)
+    finally:
+        rt.close()
+
+
+def test_sketch_counts_real_rows_not_padding():
+    """A sampled window of 2 requests padded to bucket 8 records the 2
+    requests' tokens only: the pad rows (copies of row 0) neither count
+    in the sketch nor take places in its candidate ring."""
+    rt = _mk_rt()
+    try:
+        rt.sampler.pin(1)                        # every window samples
+        rows = make_request_rows(TINY, jax.random.PRNGKey(11), 2)
+        rt.step_many([make_request_batch(rows, 8)])
+        st = rt._host_instr_snapshot()["vocab_embed#0"]
+        real = np.concatenate([r["tokens"] for r in rows])
+        assert int(st["total"]) == real.size
+        cand = np.asarray(st["cand"])
+        assert (cand >= 0).sum() == real.size
+        assert set(cand[cand >= 0].tolist()) == set(real.tolist())
+    finally:
+        rt.close()
+
+
 def test_warm_fused_precompiles_every_role():
     """After warm_fused, serving that shape never compiles inline —
     sampled windows (instrumented twin) and deopt windows (generic)
